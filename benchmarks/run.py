@@ -52,6 +52,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
     quick = not args.full
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (adaptive_depth, adaptive_engine, bytes_lean,
                             constrained_tree, engine_overlap, fault_engine,

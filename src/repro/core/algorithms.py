@@ -131,8 +131,9 @@ def _fusable(obj, constraint, attrs) -> bool:
     """May the fused single-launch selection replace the step-wise scan?
 
     Unconstrained selection fuses whenever the objective exposes a
-    ``fused_select`` hook.  Of the hereditary constraint classes,
-    :class:`Knapsack` (a weight operand + SMEM used-weight scalar —
+    ``fused_select`` hook, attribute columns or not.  Of the hereditary
+    constraint classes, :class:`Knapsack` (a weight operand + SMEM
+    used-weight scalar —
     ``fused_knapsack`` on the objective advertises it) and
     :class:`PartitionMatroid` (a group-id operand + SMEM per-group count
     vector — ``fused_partition``) have fused encodings, as does an
@@ -143,7 +144,7 @@ def _fusable(obj, constraint, attrs) -> bool:
             and hasattr(obj, "fused_select")):
         return False
     if constraint is None or isinstance(constraint, Unconstrained):
-        return attrs is None
+        return True            # attributes matter only to a constraint
     parts = _fused_parts(constraint)
     if parts is None or attrs is None:
         return False

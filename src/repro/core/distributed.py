@@ -20,20 +20,11 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import algorithms
-
-if hasattr(jax, "shard_map"):                       # jax ≥ 0.6
-    _shard_map = jax.shard_map
-else:                                               # jax 0.4.x fallback
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-
 
 class RoundResult(NamedTuple):
     sol_rows: jax.Array   # (M, k, d)
@@ -47,8 +38,6 @@ class RoundResult(NamedTuple):
 
 def make_submod_mesh(devices=None) -> Mesh:
     """All devices flattened into one 'machines' axis."""
-    import numpy as np
-
     devices = jax.devices() if devices is None else devices
     return Mesh(np.asarray(devices), ("machines",))
 
@@ -153,7 +142,7 @@ def run_round(obj, blocks: jax.Array, bmask: jax.Array, keys: jax.Array,
     in_specs = (P(), spec, spec, spec, spec)
     if meta is not None:
         in_specs = in_specs + (spec,)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs=(spec, spec, spec, spec, spec),
@@ -195,6 +184,37 @@ def shard_round_inputs(mesh: Mesh, blocks, bmask, keys, meta=None):
     return out + (jax.device_put(meta, spec),)
 
 
+# On a TPU v5e (libtpu 0.0.34) the round program halted the core
+# ("on-device check-failure" in its row gather) on a 2.46 GB wave uploaded
+# in one host transfer; the same program ran on waves uploaded as 1.23 GB
+# and 0.79 GB transfers and on a 2.46 GB wave made on the device.  Single
+# transfers of 2^31 - 8 KiB and 2^31 + 4 KiB of other data read back and
+# gathered correctly, so transfer size alone does not explain the fault.
+# Uploads travel in pieces no larger than the largest transfer seen to run
+# and are joined on the device.
+LARGEST_GOOD_TRANSFER_BYTES = 1_228_800_000
+MAX_TRANSFER_BYTES = 1 << 30
+
+
+def _upload_to(x: np.ndarray, device) -> jax.Array:
+    pieces = -(-x.nbytes // MAX_TRANSFER_BYTES)
+    if pieces <= 1:
+        return jax.device_put(x, device)
+    return jnp.concatenate([jax.device_put(p, device)
+                            for p in np.array_split(x, pieces)])
+
+
+def upload(x, sharding=None) -> jax.Array:
+    """``jax.device_put`` of a host array in transfers of at most
+    ``MAX_TRANSFER_BYTES`` per device, each device's part joined there."""
+    x = np.asarray(x)
+    if sharding is None or x.nbytes <= MAX_TRANSFER_BYTES:
+        return _upload_to(x, sharding)
+    shards = [_upload_to(x[idx], dev) for dev, idx in
+              sharding.addressable_devices_indices_map(x.shape).items()]
+    return jax.make_array_from_single_device_arrays(x.shape, sharding, shards)
+
+
 def stage_wave_inputs(mesh: Mesh | None, blocks_np, bmask_np, meta_np=None):
     """Host→device staging of one ingestion wave's gathered buffers.
 
@@ -211,13 +231,6 @@ def stage_wave_inputs(mesh: Mesh | None, blocks_np, bmask_np, meta_np=None):
     columns); the return grows to a 3-tuple so narrow feature blocks and
     their fp32 metadata stage under the same sharding.
     """
-    if mesh is None:
-        if meta_np is None:
-            return jnp.asarray(blocks_np), jnp.asarray(bmask_np)
-        return (jnp.asarray(blocks_np), jnp.asarray(bmask_np),
-                jnp.asarray(meta_np))
-    spec = NamedSharding(mesh, P("machines"))
-    if meta_np is None:
-        return jax.device_put(blocks_np, spec), jax.device_put(bmask_np, spec)
-    return (jax.device_put(blocks_np, spec), jax.device_put(bmask_np, spec),
-            jax.device_put(meta_np, spec))
+    spec = None if mesh is None else NamedSharding(mesh, P("machines"))
+    arrays = (blocks_np, bmask_np) + (() if meta_np is None else (meta_np,))
+    return tuple(upload(a, spec) for a in arrays)

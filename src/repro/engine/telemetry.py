@@ -704,27 +704,19 @@ def format_report(m: RunManifest) -> list[str]:
 @contextlib.contextmanager
 def profiler_session(profile_dir: str | None) -> Iterator[None]:
     """Bracket a block with ``jax.profiler`` start/stop when a directory
-    is given (the ``--profile-dir`` flag); no-op otherwise.  Failure to
-    start the profiler (headless build, missing deps) degrades to the
-    no-op with a warning — profiling must never fail the run."""
+    is given (the ``--profile-dir`` flag); no-op otherwise.  A profiler
+    that cannot start raises: a run asked for a device profile must not
+    finish without one."""
     if not profile_dir:
         yield
         return
     import jax
-    started = False
-    try:
-        os.makedirs(profile_dir, exist_ok=True)
-        jax.profiler.start_trace(profile_dir)
-        started = True
-    except Exception as exc:                   # pragma: no cover - env dep
-        import warnings
-        warnings.warn(f"jax.profiler unavailable ({exc}); continuing "
-                      f"without a device profile", RuntimeWarning)
+    os.makedirs(profile_dir, exist_ok=True)
+    jax.profiler.start_trace(profile_dir)
     try:
         yield
     finally:
-        if started:
-            jax.profiler.stop_trace()
+        jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------------

@@ -27,6 +27,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import VMEM_LIMIT_BYTES
+from repro.kernels.ref import contract
 
 
 def _kernel(x_ref, e_ref, cm_ref, *rest, quantized: bool = False,
@@ -49,8 +53,7 @@ def _kernel(x_ref, e_ref, cm_ref, *rest, quantized: bool = False,
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)              # (bn, 1)
     e2 = jnp.sum(e * e, axis=-1, keepdims=True).T            # (1, bm)
     # MXU contraction + VPU epilogue, all in VMEM:
-    d2 = x2 + e2 - 2.0 * jax.lax.dot_general(
-        x, e, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    d2 = x2 + e2 - 2.0 * contract(x, e)
     d2 = jnp.maximum(d2, 0.0)
     contrib = jnp.maximum(cm - d2, 0.0)                      # (bn, bm)
     if weighted:
@@ -110,6 +113,8 @@ def exemplar_gains_pallas(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)
     # NOTE: returns the raw sum; ops.py divides by the *unpadded* eval-set size.

@@ -30,11 +30,12 @@ Grid: ``(k, n/bn)`` — steps major, candidate row blocks minor.  TPU grid
 iteration is sequential, so scratch state (``cur_min``, availability, the
 argmax accumulator) persists across blocks and steps.
 
-Capacity contract (enforced by ``ops._greedy_select_fits_vmem``): ``X`` and
-``E`` must fit VMEM simultaneously (n·d + m·d fp32 words + one (bn, m)
-gains tile).  For per-machine blocks of the tree driver (n = μ, m = |E|,
-both a few thousand) this holds comfortably; oversized ``auto`` problems
-are dispatched to the pure-jnp fused reference instead.
+Capacity contract (enforced by ``ops._selection_vmem_bytes`` against the
+``VMEM_LIMIT_BYTES`` the kernel is compiled with): ``X``, ``E`` and the
+per-row columns must fit VMEM simultaneously, double-buffered and stored in
+(sublane, 128-lane) tiles, next to the (bn, m) gains tiles.  A block of
+μ = 1,000 rows fits at d = 64 but not at d = 1,024; oversized ``auto``
+problems are dispatched to the pure-jnp fused reference instead.
 
 Padding contract: candidate rows are zero-padded with availability 0 (never
 selected); ``E`` rows and ``cur_min`` are zero-padded so padded eval columns
@@ -74,6 +75,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import VMEM_LIMIT_BYTES
+from repro.kernels.ref import contract
+
 NEG_INF = -1e30  # python float — jnp scalars would be captured consts in-kernel
 
 
@@ -97,8 +101,8 @@ def _kernel(x_ref, e_ref, cm0_ref, av0_ref, *rest, bn: int, m_true: int,
     gid_ref = next(it) if caps is not None else None
     xs_ref = next(it) if quantized else None
     xz_ref = next(it) if quantized else None
-    sel_ref, cmout_ref, cm_s, av_s, bv_s, bi_s = (
-        next(it), next(it), next(it), next(it), next(it), next(it))
+    sel_ref, cmout_ref, cm_s, av_s, row_s, bv_s, bi_s = (
+        next(it), next(it), next(it), next(it), next(it), next(it), next(it))
     used_s = next(it) if budget is not None else None
     cnt_s = next(it) if caps is not None else None
     s = pl.program_id(0)
@@ -116,15 +120,21 @@ def _kernel(x_ref, e_ref, cm0_ref, av0_ref, *rest, bn: int, m_true: int,
             for g in range(len(caps)):
                 cnt_s[g] = 0
 
+    def block(ref):
+        # rows of candidate block i; a single block is read whole, so narrow
+        # dtypes never need a dynamic offset aligned to their packed tiling
+        if ref.shape[0] == bn:
+            return ref[...]
+        return ref[pl.ds(pl.multiple_of(i * bn, bn), bn), :]
+
     # ---- gains for candidate block i against the resident eval set -------
-    x = x_ref[pl.ds(i * bn, bn), :]                      # (bn, d) narrow ok
+    x = block(x_ref)                                     # (bn, d) narrow ok
     e = e_ref[...]                                       # (mp, d)
     xf = x.astype(jnp.float32)
     if quantized:
         # in-kernel dequant: VMEM held the narrow rows, the fp32 affine
         # below matches ref.dequantize_rows bit-for-bit (IEEE mult-add)
-        xf = (xf * xs_ref[pl.ds(i * bn, bn), :]
-              + xz_ref[pl.ds(i * bn, bn), :])
+        xf = xf * block(xs_ref) + block(xz_ref)
     if compute_dtype is not None:
         xc, ec = xf.astype(compute_dtype), e.astype(compute_dtype)
     else:
@@ -132,19 +142,18 @@ def _kernel(x_ref, e_ref, cm0_ref, av0_ref, *rest, bn: int, m_true: int,
     ef = e.astype(jnp.float32)
     x2 = jnp.sum(xf * xf, axis=-1, keepdims=True)        # (bn, 1)
     e2 = jnp.sum(ef * ef, axis=-1, keepdims=True).T      # (1, mp)
-    xy = jax.lax.dot_general(xc, ec, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    xy = contract(xc, ec)
     d2 = jnp.maximum(x2 + e2 - 2.0 * xy, 0.0)            # (bn, mp)
     cm = cm_s[...]                                       # (1, mp)
     g = jnp.sum(jnp.maximum(cm - d2, 0.0), axis=-1,
                 keepdims=True) / m_true                  # (bn, 1)
-    av = av_s[pl.ds(i * bn, bn), :]                      # (bn, 1)
+    av = block(av_s)                                     # (bn, 1)
     feas = av > 0
     if budget is not None:
-        w = w_ref[pl.ds(i * bn, bn), :]                  # (bn, 1)
+        w = block(w_ref)                                 # (bn, 1)
         feas = feas & (used_s[0] + w <= budget + tol)
     if caps is not None:
-        gid = gid_ref[pl.ds(i * bn, bn), :]              # (bn, 1) int32
+        gid = block(gid_ref)                             # (bn, 1) int32
         # static unrolled conjunction over the (tiny) group set: each
         # group's open/closed bit is one SMEM scalar compare, broadcast
         # against the block's gid column — no SMEM gather required
@@ -159,27 +168,24 @@ def _kernel(x_ref, e_ref, cm0_ref, av0_ref, *rest, bn: int, m_true: int,
     rows = jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
     barg = jnp.min(jnp.where(g == bmax, rows, bn))       # lowest index on ties
     gidx = i * bn + barg
-
-    @pl.when(i == 0)
-    def _first():
-        bv_s[0] = bmax
-        bi_s[0] = gidx
-
-    better = (i != 0) & (bmax > bv_s[0])                 # strict: low block wins
+    better = (i == 0) | (bmax > bv_s[0])        # strict: low block wins
 
     @pl.when(better)
     def _acc():
         bv_s[0] = bmax
         bi_s[0] = gidx
+        # keep the leader's fp32 (dequantized) row: one nonzero term per
+        # column, so the masked sum is exact and the commit below never
+        # slices a single row out of packed bf16/int8 storage
+        row_s[...] = jnp.sum(jnp.where(rows == barg, xf, 0.0), axis=0,
+                             keepdims=True)
 
     # ---- end of step: commit winner, refresh state in VMEM ---------------
     @pl.when(i == nb - 1)
     def _finish():
         bi = bi_s[0]
         ok = bv_s[0] > NEG_INF / 2
-        xs = x_ref[pl.ds(bi, 1), :].astype(jnp.float32)  # (1, d) winner row
-        if quantized:
-            xs = xs * xs_ref[pl.ds(bi, 1), :] + xz_ref[pl.ds(bi, 1), :]
+        xs = row_s[...]                                  # (1, d) winner row
         d2b = jnp.sum((ef - xs) ** 2, axis=-1,
                       keepdims=True).T                   # (1, mp) — objective's
         cur = cm_s[...]                                  # difference form
@@ -194,7 +200,10 @@ def _kernel(x_ref, e_ref, cm0_ref, av0_ref, *rest, bn: int, m_true: int,
             for grp in range(len(caps)):
                 cnt_s[grp] = jnp.where(ok & (gv == grp), cnt_s[grp] + 1,
                                        cnt_s[grp])
-        sel_ref[0, 0] = jnp.where(ok, bi, jnp.int32(-1))
+        # step s's pick lands in lane s of the resident (1, k) output row
+        lanes = jax.lax.broadcasted_iota(jnp.int32, sel_ref.shape, 1)
+        sel_ref[...] = jnp.where(lanes == s, jnp.where(ok, bi, -1),
+                                 sel_ref[...])
 
         @pl.when(s == ns - 1)
         def _flush():
@@ -245,6 +254,7 @@ def greedy_select_pallas(
     scratch = [
         pltpu.VMEM((1, mp), jnp.float32),            # running cur_min
         pltpu.VMEM((n, 1), jnp.float32),             # availability
+        pltpu.VMEM((1, d), jnp.float32),             # leading row, fp32
         pltpu.SMEM((1,), jnp.float32),               # best value so far
         pltpu.SMEM((1,), jnp.int32),                 # best index so far
     ]
@@ -267,14 +277,16 @@ def greedy_select_pallas(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1), lambda s, i: (s, 0)),   # per-step selection
+            pl.BlockSpec((1, k), lambda s, i: (0, 0)),   # per-step selection
             pl.BlockSpec((1, mp), lambda s, i: (0, 0)),  # final cur_min
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((k, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, k), jnp.int32),
             jax.ShapeDtypeStruct((1, mp), jnp.float32),
         ],
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)
-    return sel[:, 0], cm[0]
+    return sel[0], cm[0]

@@ -15,8 +15,8 @@ The wrappers own the padding contract so kernels can assume exact tiling.
 an entire k-item exemplar-clustering greedy selection in a single launch
 (see kernels/greedy_select.py).  Its dispatch adds one rule on top of the
 policy above: the Pallas path additionally requires the candidate block and
-eval set to fit VMEM together (``(n + m)·d`` fp32 words plus one ``(bn, m)``
-gains tile — see ``_greedy_select_fits_vmem``); oversized ``auto`` problems
+eval set to fit the kernels' scoped VMEM limit together (tiled, double-
+buffered — see ``_selection_vmem_bytes``); oversized ``auto`` problems
 take the pure-jnp fused reference instead.  Both impls are bit-identical to
 the step-wise greedy, lowest-index tie-breaking included, so β-niceness
 guarantees transfer unchanged.  Scope of that contract: exact within an
@@ -29,10 +29,12 @@ equality assert doubles as the canary.
 """
 from __future__ import annotations
 
+import collections
+
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
+from repro.kernels import VMEM_LIMIT_BYTES, ref
 from repro.kernels.exemplar_gains import exemplar_gains_pallas
 from repro.kernels.greedy_select import greedy_select_pallas
 from repro.kernels.threshold_select import threshold_select_pallas
@@ -59,6 +61,37 @@ def _interpret() -> bool:
     return not _on_tpu()
 
 
+# Which implementation each selection wrapper lowered to, counted when a
+# program is traced (one entry per compiled program, not per launch):
+# entry points print it, so a fallback to the reference is never silent.
+PATHS: collections.Counter = collections.Counter()
+
+
+def _route(kernel: str, impl: str, oversized: bool = False,
+           dynamic_params: bool = False) -> str:
+    """Pick ``"pallas"`` or a ``"ref…"`` path for one call and count it."""
+    if not _use_pallas(impl):
+        path = "ref"
+    elif impl == "auto" and oversized:
+        path = "ref:oversized"
+    elif impl == "auto" and dynamic_params:
+        path = "ref:dynamic-params"
+    else:
+        path = "pallas"
+    label = "interpret" if path == "pallas" and not _on_tpu() else path
+    PATHS[(kernel, label)] += 1
+    return path
+
+
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def _sublanes(dtype) -> int:
+    # rows per (sublane, lane) tile: 8 for 32-bit, 16 for bf16, 32 for int8
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
 def _pad_rows(x: jax.Array, mult: int, value: float = 0.0) -> jax.Array:
     n = x.shape[0]
     pad = (-n) % mult
@@ -83,13 +116,16 @@ def exemplar_gains(
     *,
     impl: str = "auto",
     bn: int = 256,
-    bm: int = 256,
+    bm: int = 128,
     compute_dtype=None,
     x_scale: jax.Array | None = None,
     x_zp: jax.Array | None = None,
     eval_weights: jax.Array | None = None,
 ) -> jax.Array:
     """Marginal gains for exemplar clustering. See kernels/exemplar_gains.py.
+
+    The (256, 128) blocks fit the scoped VMEM limit up to d = 4,096 with
+    full-precision fp32 contraction (tests/test_tpu_compile.py).
 
     ``x_scale``/``x_zp`` (both or neither, per candidate row) dequantize
     int8-stored candidates in-kernel: VMEM holds the narrow rows, gain math
@@ -100,7 +136,7 @@ def exemplar_gains(
     ``None`` is the unweighted path, bit-identical to weights of exactly 1.0.
     """
     assert (x_scale is None) == (x_zp is None), "x_scale and x_zp pair up"
-    if not _use_pallas(impl):
+    if _route("exemplar_gains", impl) != "pallas":
         return ref.exemplar_gains(X, E, cur_min, compute_dtype=compute_dtype,
                                   x_scale=x_scale, x_zp=x_zp,
                                   eval_weights=eval_weights)
@@ -120,21 +156,48 @@ def exemplar_gains(
     return raw[:n] / m
 
 
-# VMEM budget for the fused selection kernel's resident operands: 16 MB/core
-# minus headroom for the (bn, m) gains tile, availability and accumulators.
-_GREEDY_SELECT_VMEM_BUDGET = 12 * 1024 * 1024
+def _tile_bytes(rows: int, cols: int, itemsize: int = 4) -> int:
+    """VMEM bytes of a (rows, cols) array stored in (sublane, 128-lane)
+    tiles — an (n, 1) fp32 column costs n·128·4 bytes, not n·4."""
+    return (_round_up(rows, 8 * (4 // itemsize)) * _round_up(cols, 128)
+            * itemsize)
 
 
-def _greedy_select_fits_vmem(n: int, m: int, d: int, bn: int,
-                             x_itemsize: int = 4) -> bool:
-    # X at its storage itemsize (narrow candidates are the point of the
-    # quantized path: halving bytes/row doubles the block that fits), E,
-    # cur_min, avail (+ the knapsack weight, partition group-id and dequant
-    # scale/zp columns, ≤ 4n words more — budgeted unconditionally so
-    # constrained/quantized dispatch can't regress) fp32/int32
-    resident = n * d * x_itemsize + (m * d + m + 5 * n) * 4
-    tile = bn * m * 4                             # one gains tile
-    return resident + tile <= _GREEDY_SELECT_VMEM_BUDGET
+def _selection_vmem_bytes(n: int, m: int, d: int, bn: int, *,
+                          x_itemsize: int = 4, cols: int = 1,
+                          streamed: bool = False) -> int:
+    """Scoped VMEM a selection kernel needs, as the tree lays it out.
+
+    Machines are vmapped, so every input block is double-buffered even
+    under a constant index map.  ``cols`` counts the per-row (·, 1)
+    columns (availability plus knapsack weights, group ids, dequant
+    scale/zero-point).  The greedy megakernel keeps X and its columns
+    resident (n rows); threshold_select streams them (``streamed``, bn
+    rows).  Temporaries: two (bn, m) distance/gain tiles, four (bn, d)
+    fp32 tiles and one (m, d) — the dequantized X block and the operand
+    splits of the full-precision fp32 contraction.  Calibrated against
+    Mosaic's scoped allocations on v5e (libtpu 0.0.34): it overstates them
+    by at most ~1.5 MiB; tests/test_tpu_compile.py holds the fit.
+    """
+    rows = bn if streamed else n
+    inputs = (_tile_bytes(rows, d, x_itemsize) + _tile_bytes(m, d)
+              + _tile_bytes(1, m) + cols * _tile_bytes(rows, 1))
+    outputs = _tile_bytes(1, m) + (_tile_bytes(bn, 1) if streamed
+                                   else _tile_bytes(1, 128))
+    scratch = _tile_bytes(1, m) + (0 if streamed else
+                                   _tile_bytes(n, 1) + _tile_bytes(1, d))
+    temps = (2 * _tile_bytes(bn, m) + 4 * _tile_bytes(bn, d)
+             + _tile_bytes(m, d))
+    return 2 * (inputs + outputs) + scratch + temps
+
+
+def _fits_vmem(n: int, m: int, d: int, bn: int, **kw) -> bool:
+    return _selection_vmem_bytes(n, m, d, bn, **kw) <= VMEM_LIMIT_BYTES
+
+
+def _n_cols(weights, group_ids, x_scale) -> int:
+    return (1 + (weights is not None) + (group_ids is not None)
+            + 2 * (x_scale is not None))
 
 
 def greedy_select(
@@ -173,7 +236,7 @@ def greedy_select(
     bit-identity contract extends to every fused-constraint combination.
 
     The Pallas megakernel keeps X and E resident in VMEM, so ``auto``
-    additionally requires them to fit (:func:`_greedy_select_fits_vmem`);
+    additionally requires them to fit (:func:`_selection_vmem_bytes`);
     oversized problems take the reference path (XLA hoists the step-
     invariant contraction, so it degrades gracefully rather than erroring).
     ``impl="pallas"`` overrides the capacity check (tests, experiments).
@@ -188,9 +251,14 @@ def greedy_select(
     assert (weights is None) == (budget is None), "weights and budget pair up"
     assert (group_ids is None) == (caps is None), "group_ids and caps pair up"
     assert (x_scale is None) == (x_zp is None), "x_scale and x_zp pair up"
-    oversized = not _greedy_select_fits_vmem(X.shape[0], E.shape[0],
-                                             X.shape[1], bn,
-                                             x_itemsize=X.dtype.itemsize)
+    n, m = X.shape[0], E.shape[0]
+    # the block size is pure tiling here (the argmax is global, ties go to
+    # the lowest index), so round it to the sublane tile of X's dtype
+    bn = _round_up(min(bn, max(8, n)), _sublanes(X.dtype))
+    bm = min(bm, max(8, m))
+    oversized = not _fits_vmem(_round_up(n, bn), _round_up(m, bm),
+                               X.shape[1], bn, x_itemsize=X.dtype.itemsize,
+                               cols=_n_cols(weights, group_ids, x_scale))
     dynamic_params = (isinstance(budget, jax.Array)
                       or isinstance(caps, jax.Array)
                       or eval_weights is not None)
@@ -198,17 +266,13 @@ def greedy_select(
         raise ValueError("greedy_select: traced budget/caps and eval_weights "
                          "require the fused reference impl (the Pallas "
                          "megakernel takes them as compile-time statics)")
-    if not _use_pallas(impl) or (impl == "auto" and (oversized
-                                                    or dynamic_params)):
+    if _route("greedy_select", impl, oversized, dynamic_params) != "pallas":
         return ref.greedy_select(X, E, cur_min, mask, k,
                                  compute_dtype=compute_dtype,
                                  weights=weights, budget=budget,
                                  group_ids=group_ids, caps=caps,
                                  x_scale=x_scale, x_zp=x_zp,
                                  eval_weights=eval_weights)
-    n, m = X.shape[0], E.shape[0]
-    bn = min(bn, max(8, n))
-    bm = min(bm, max(8, m))
     Xp = _pad_rows(X, bn)
     avp = _pad_rows(mask.astype(jnp.float32), bn)
     Ep = _pad_rows(E, bm)
@@ -277,8 +341,8 @@ def threshold_select(
     to the fused reference, exactly as :func:`greedy_select` does.
 
     The Pallas path streams X block-by-block but keeps E VMEM-resident,
-    so ``auto`` reuses the greedy VMEM budget check (conservative: the
-    megakernel actually admits larger candidate blocks than greedy).
+    so ``auto`` checks E, one X block and the gains tiles against the
+    scoped VMEM limit (:func:`_selection_vmem_bytes` with ``streamed``).
     """
     assert (weights is None) == (budget is None), "weights and budget pair up"
     assert (group_ids is None) == (caps is None), "group_ids and caps pair up"
@@ -293,8 +357,10 @@ def threshold_select(
         G = len(caps) if isinstance(caps, (tuple, list)) else caps.shape[0]
     counts0 = (jnp.zeros((max(G, 1),), jnp.int32) if counts is None
                else jnp.asarray(counts, jnp.int32))
-    oversized = not _greedy_select_fits_vmem(n, m, X.shape[1], bn,
-                                             x_itemsize=X.dtype.itemsize)
+    oversized = not _fits_vmem(_round_up(n, bn), _round_up(m, bm),
+                               X.shape[1], bn, x_itemsize=X.dtype.itemsize,
+                               cols=_n_cols(weights, group_ids, x_scale),
+                               streamed=True)
     dynamic_params = (isinstance(budget, jax.Array)
                       or isinstance(caps, jax.Array)
                       or eval_weights is not None)
@@ -303,8 +369,8 @@ def threshold_select(
                          "eval_weights require the fused reference impl "
                          "(the Pallas megakernel takes them as "
                          "compile-time statics)")
-    if not _use_pallas(impl) or (impl == "auto" and (oversized
-                                                    or dynamic_params)):
+    if _route("threshold_select", impl, oversized,
+              dynamic_params) != "pallas":
         return ref.threshold_select(X, E, cur_min, mask,
                                     jnp.asarray(tau, jnp.float32),
                                     used0, counts0, count0, k=k, bn=bn,

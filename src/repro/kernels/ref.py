@@ -11,11 +11,25 @@ import jax
 import jax.numpy as jnp
 
 
+def contract(X: jax.Array, Y: jax.Array) -> jax.Array:
+    """(n, d), (m, d) -> (n, m) = X Yᵀ, accumulated in fp32.
+
+    fp32 operands contract at full fp32 precision: the TPU's default would
+    round them to bf16 first, and both the Pallas kernels and this oracle
+    promise fp32 gains.  (The CPU computes fp32 either way.)
+    """
+    full = X.dtype == jnp.float32 and Y.dtype == jnp.float32
+    return jax.lax.dot_general(
+        X, Y, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if full else None,
+        preferred_element_type=jnp.float32)
+
+
 def pairwise_sqdist(X: jax.Array, Y: jax.Array) -> jax.Array:
     """(n, d), (m, d) -> (n, m) squared euclidean distances."""
     x2 = jnp.sum(X * X, axis=-1, keepdims=True)          # (n, 1)
     y2 = jnp.sum(Y * Y, axis=-1, keepdims=True).T        # (1, m)
-    d2 = x2 + y2 - 2.0 * (X @ Y.T)
+    d2 = x2 + y2 - 2.0 * contract(X, Y)
     return jnp.maximum(d2, 0.0)
 
 
@@ -32,8 +46,7 @@ def _sqdist(X: jax.Array, E: jax.Array, compute_dtype=None) -> jax.Array:
     Xc, Ec = X.astype(compute_dtype), E.astype(compute_dtype)
     x2 = jnp.sum(X.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
     e2 = jnp.sum(E.astype(jnp.float32) ** 2, axis=-1, keepdims=True).T
-    xy = jax.lax.dot_general(Xc, Ec, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    xy = contract(Xc, Ec)
     return jnp.maximum(x2 + e2 - 2.0 * xy, 0.0)
 
 
@@ -50,6 +63,23 @@ def dequantize_rows(X: jax.Array, x_scale: jax.Array | None = None,
     if x_scale is not None:
         Xf = Xf * x_scale[:, None] + x_zp[:, None]
     return Xf
+
+
+def prefix_sum(v: jax.Array, roll=None) -> jax.Array:
+    """Inclusive prefix sum along axis 0 in log-step (Hillis–Steele) form.
+
+    ``threshold_select`` needs cumulative counts and weights inside its
+    Pallas kernel, where Mosaic has no cumsum; both impls run exactly this
+    sequence of shifted elementwise adds (the kernel passes ``pltpu.roll``
+    as ``roll``), so their float sums agree bit for bit.
+    """
+    roll = roll or (lambda a, off: jnp.roll(a, off, axis=0))
+    rows = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    off = 1
+    while off < v.shape[0]:
+        v = v + jnp.where(rows >= off, roll(v, off), jnp.zeros_like(v))
+        off *= 2
+    return v
 
 
 def exemplar_gains(X: jax.Array, E: jax.Array, cur_min: jax.Array,
@@ -253,16 +283,16 @@ def threshold_select(X: jax.Array, E: jax.Array, cur_min: jax.Array,
                 open_any = open_any | ((gidb == grp)
                                        & (counts[grp] < caps_arr[grp]))
             q = q & open_any
-        cumn = jnp.cumsum(q.astype(jnp.int32))
+        cumn = prefix_sum(q.astype(jnp.int32))
         violate = (count + cumn) > k
         if weights is not None:
-            cumw = jnp.cumsum(jnp.where(q, wb, 0.0))
+            cumw = prefix_sum(jnp.where(q, wb, 0.0))
             violate = violate | (used + cumw > budget + KNAPSACK_TOL)
         if caps is not None:
             for grp in range(G):
-                cg = jnp.cumsum((q & (gidb == grp)).astype(jnp.int32))
+                cg = prefix_sum((q & (gidb == grp)).astype(jnp.int32))
                 violate = violate | ((counts[grp] + cg) > caps_arr[grp])
-        acc = q & (jnp.cumsum(violate.astype(jnp.int32)) == 0) & ~stopped
+        acc = q & (prefix_sum(violate.astype(jnp.int32)) == 0) & ~stopped
         stopped = stopped | jnp.any(violate & q)
         count = count + jnp.sum(acc.astype(jnp.int32))
         if weights is not None:

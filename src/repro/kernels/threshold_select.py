@@ -34,7 +34,7 @@ Per block, with block-entry state:
     counterpart; its contract is bit-identity to ``ref.threshold_select``
     at the same ``bn``).
 
-Scalar launch state rides in two tiny VMEM operands — ``fscal`` (1, 2)
+Scalar launch state rides in two tiny SMEM operands — ``fscal`` (1, 2)
 fp32 ``[τ, used]`` and ``iscal`` (1, 1+G) int32 ``[count, counts…]`` —
 copied into SMEM scratch at block 0, so the τ-ladder driver can run as a
 ``lax.while_loop`` without retracing.  The kernel returns only
@@ -58,12 +58,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import VMEM_LIMIT_BYTES
+from repro.kernels.ref import contract, prefix_sum
+
 INF = float("inf")  # python float — jnp scalars would be captured consts
 
 
 def _knapsack_tol() -> float:
     from repro.core.constraints import KNAPSACK_TOL
     return KNAPSACK_TOL
+
+
+def _prefix_sum(v):
+    # inclusive prefix sum down the sublane axis; Mosaic has no cumsum, so
+    # this is the log-step form ref.prefix_sum computes with the same adds
+    return prefix_sum(v, roll=lambda a, off: pltpu.roll(a, off, 0))
 
 
 def _kernel(x_ref, e_ref, cm0_ref, av_ref, fscal_ref, iscal_ref, *rest,
@@ -111,8 +120,7 @@ def _kernel(x_ref, e_ref, cm0_ref, av_ref, fscal_ref, iscal_ref, *rest,
     ef = e.astype(jnp.float32)
     x2 = jnp.sum(xf * xf, axis=-1, keepdims=True)        # (bn, 1)
     e2 = jnp.sum(ef * ef, axis=-1, keepdims=True).T      # (1, mp)
-    xy = jax.lax.dot_general(xc, ec, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    xy = contract(xc, ec)
     d2 = jnp.maximum(x2 + e2 - 2.0 * xy, 0.0)            # (bn, mp)
     cm = cm_s[...]                                       # (1, mp)
     g = jnp.sum(jnp.maximum(cm - d2, 0.0), axis=-1,
@@ -136,16 +144,16 @@ def _kernel(x_ref, e_ref, cm0_ref, av_ref, fscal_ref, iscal_ref, *rest,
         q = q & open_any
 
     # ---- prefix-stop accept: monotone cumulative feasibility -------------
-    cumn = jnp.cumsum(q.astype(jnp.int32), axis=0)       # (bn, 1) inclusive
+    cumn = _prefix_sum(q.astype(jnp.int32))              # (bn, 1) inclusive
     violate = (count_s[0] + cumn) > k
     if budget is not None:
-        cumw = jnp.cumsum(jnp.where(q, w, 0.0), axis=0)
+        cumw = _prefix_sum(jnp.where(q, w, 0.0))
         violate = violate | (used_s[0] + cumw > budget + tol)
     if caps is not None:
         for grp in range(len(caps)):
-            cg = jnp.cumsum((q & (gid == grp)).astype(jnp.int32), axis=0)
+            cg = _prefix_sum((q & (gid == grp)).astype(jnp.int32))
             violate = violate | ((cnt_s[grp] + cg) > caps[grp])
-    acc = q & (jnp.cumsum(violate.astype(jnp.int32), axis=0) == 0) \
+    acc = q & (_prefix_sum(violate.astype(jnp.int32)) == 0) \
             & (stop_s[0] == 0)
 
     # ---- commit: scalar state, stop flag, cur_min batch fold -------------
@@ -211,8 +219,8 @@ def threshold_select_pallas(
         pl.BlockSpec((mp, d), res),                  # E resident
         pl.BlockSpec((1, mp), res),                  # cur_min seed
         pl.BlockSpec((bn, 1), blk),                  # availability
-        pl.BlockSpec((1, 2), res),                   # [tau, used] fp32
-        pl.BlockSpec((1, 1 + G), res),               # [count, counts…] int32
+        pl.BlockSpec(memory_space=pltpu.SMEM),       # [tau, used] fp32
+        pl.BlockSpec(memory_space=pltpu.SMEM),       # [count, counts…] int32
     ]
     scratch = [
         pltpu.VMEM((1, mp), jnp.float32),            # running cur_min
@@ -248,6 +256,8 @@ def threshold_select_pallas(
             jax.ShapeDtypeStruct((1, mp), jnp.float32),
         ],
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)
     return acc[:, 0], cm[0]

@@ -38,11 +38,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *, bt: int):
     def step(t, S):
         kv = k[t][:, None] * v[t][None, :]               # (Dk, Dv)
         y = r[t][None, :] @ (S + u[:, None] * kv)        # (1, Dv)
-        # size-1 dslices (not bare ints) — bare int indices don't lower on
-        # every pallas version
-        pl.store(o_ref, (pl.dslice(0, 1), pl.dslice(0, 1), pl.dslice(t, 1),
-                         slice(None)),
-                 y[None, None].astype(o_ref.dtype))
+        o_ref[0, 0, pl.ds(t, 1), :] = y.astype(o_ref.dtype)
         return w[t][:, None] * S + kv
 
     s_scr[...] = jax.lax.fori_loop(0, bt, step, s_scr[...])

@@ -6,16 +6,11 @@ device state — the dry-run sets XLA_FLAGS before any jax initialisation.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:                                    # jax ≥ 0.5 explicit-sharding API
-    from jax.sharding import AxisType
 
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:                     # jax 0.4.x: all axes are Auto already
-
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {}
+def _axis_kwargs(n_axes: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -127,8 +127,10 @@ three.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -148,6 +150,7 @@ from repro.engine import (ENGINES, FaultInjector, FaultPolicy, FaultProfile,
                           profiler_session, suggest_prefetch_depth)
 from repro.data import datasets
 from repro.data.sources import ShardedSource
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def synth_attrs(constraint, n: int, seed: int) -> np.ndarray | None:
@@ -199,53 +202,34 @@ def _np_exemplar_value(E, rows, mask) -> float:
     return float(np.mean(e0) - np.mean(cur))
 
 
-def serve_smoke(args) -> None:
-    """CI-grepable exercise of the selection service without a daemon.
+@dataclasses.dataclass
+class ServeExercise:
+    """What :func:`serve_exercise` did, for its callers to report."""
+    service: Any
+    session: Any
+    cold: list
+    after: Any                  # warm re-query after the delta
+    delta: Any                  # DeltaReport
+    ingest_s: float
+    cold_s: float
+    warm_s: float
+    burst_s: float
+    delta_s: float
+    recheck: dict               # NumPy fp64 re-score of ``after``
 
-    Synthetic ingest through the wave engine, a mixed request stream
-    (two cardinalities × {unconstrained, knapsack, partition, queried})
-    issued twice as identical synchronous batches — the second pass must
-    ride the warm compile cache with zero retraces and answer
-    bit-identically (same batch composition → same bits) — plus a burst
-    through the threaded dispatcher for real queue-depth telemetry, then
-    a ~1% ground-set delta with a block-local re-solve, a NumPy re-score
-    of a served coreset (``recheck:`` line), and a validated manifest
-    with the ``serve:`` report lines.
-    """
-    from repro.engine.telemetry import (RunManifest, config_dict,
-                                        config_fingerprint)
-    from repro.serve import (Dispatcher, SelectionRequest, SelectionService,
-                             ingest, round_ladder, serve_batch)
 
-    data = np.asarray(datasets.REGISTRY[args.dataset](), np.float32)
-    n, d = data.shape
-    r = np.random.default_rng(args.seed)
-    E = data[r.choice(n, min(args.n_eval, n), replace=False)]
-    # two attribute columns: knapsack weights (col 0) + 3 groups (col 1)
-    attrs = np.zeros((n, 2), np.float32)
-    attrs[:, 0] = r.uniform(0.2, 1.0, n).astype(np.float32)
-    attrs[:, 1] = r.integers(0, 3, n).astype(np.float32)
-
-    tracer = (Tracer() if (args.trace_out or args.metrics_out
-                           or args.manifest_out) else None)
-    cfg = TreeConfig(k=args.k, capacity=args.capacity,
-                     algorithm=args.algorithm, eps=args.eps, seed=args.seed,
-                     permutation=args.permutation, engine=args.engine,
-                     hosts=args.hosts, telemetry=tracer)
-    print(f"serve-smoke: n={n} d={d} k={args.k} mu={args.capacity} "
-          f"requests={args.serve_requests} engine={args.engine}")
-    t0 = time.perf_counter()
-    st = ingest(ArraySource(data), cfg, attrs=attrs)
-    t_ingest = time.perf_counter() - t0
-    svc = SelectionService(st, E, algorithm=args.algorithm, eps=args.eps,
-                           tracer=tracer)
-
-    k2 = max(2, args.k // 2)
-    budget = float(np.quantile(attrs[:, 0], 0.6)) * min(args.k, 8)
-    cap3 = max(1, args.k // 3 + 1)
+def serve_requests(data: np.ndarray, k: int, n_requests: int,
+                   budget: float, n_groups: int = 3) -> list:
+    """The smoke's request mix: two cardinalities × {unconstrained,
+    knapsack on attribute column 0, partition on column 1, query-weighted},
+    cycled over ``n_requests`` slots."""
+    from repro.serve import SelectionRequest
+    k2 = max(2, k // 2)
+    cap = max(1, k // n_groups + 1)
+    caps = ",".join([str(cap)] * n_groups)
     reqs = []
-    for i in range(args.serve_requests):
-        k_i = args.k if i % 2 == 0 else k2
+    for i in range(n_requests):
+        k_i = k if i % 2 == 0 else k2
         kind = i % 4
         if kind == 0:
             reqs.append(SelectionRequest(k=k_i))
@@ -254,16 +238,51 @@ def serve_smoke(args) -> None:
                 k=k_i, constraint=f"knapsack:budget={budget:.4f}"))
         elif kind == 2:
             reqs.append(SelectionRequest(
-                k=k_i,
-                constraint=f"partition:caps={cap3},{cap3},{cap3}:col=1"))
+                k=k_i, constraint=f"partition:caps={caps}:col=1"))
         else:
-            reqs.append(SelectionRequest(k=k_i, query=data[(7 * i) % n]))
+            reqs.append(SelectionRequest(k=k_i,
+                                         query=data[(7 * i) % len(data)]))
+    return reqs
+
+
+def serve_exercise(data: np.ndarray, E: np.ndarray, cfg: TreeConfig, *,
+                   n_requests: int, rng: np.random.Generator, tracer=None,
+                   log=lambda _msg: None) -> ServeExercise:
+    """Drive the selection service once, asserting its contract.
+
+    Ingest ``data`` (with a knapsack-weight and a 3-group attribute column)
+    into a resident session through the wave engine; answer the
+    :func:`serve_requests` mix twice as identical synchronous batches —
+    the warm pass must ride the compile cache with zero retraces and
+    answer bit-identically; send the mix again through the threaded
+    dispatcher; apply a ~1% ground-set delta and re-query.  Every answer must be
+    feasible.  The re-query is re-scored in NumPy fp64 (``recheck``).
+    ``log`` receives one line as each stage ends.
+    """
+    from repro.serve import Dispatcher, SelectionService, ingest, serve_batch
+
+    n, d = data.shape
+    attrs = np.zeros((n, 2), np.float32)
+    attrs[:, 0] = rng.uniform(0.2, 1.0, n).astype(np.float32)
+    attrs[:, 1] = rng.integers(0, 3, n).astype(np.float32)
+
+    t0 = time.perf_counter()
+    st = ingest(ArraySource(data), cfg, attrs=attrs)
+    t_ingest = time.perf_counter() - t0
+    log(f"ingested machines={st.Mp} ingest_s={t_ingest:.3f}")
+    svc = SelectionService(st, E, algorithm=cfg.algorithm, eps=cfg.eps,
+                           tracer=tracer)
+    budget = float(np.quantile(attrs[:, 0], 0.6)) * min(cfg.k, 8)
+    reqs = serve_requests(data, cfg.k, n_requests, budget)
 
     t1 = time.perf_counter()
     cold = serve_batch(svc, reqs)
+    t2 = time.perf_counter()
     compiles_after_cold = svc.cache.compiles
+    log(f"cold requests={len(reqs)} cold_s={t2 - t1:.3f} "
+        f"entries={compiles_after_cold}")
     warm = serve_batch(svc, reqs)
-    t_serve = time.perf_counter() - t1
+    t3 = time.perf_counter()
     for c, w in zip(cold, warm):
         assert c.value == w.value and np.array_equal(c.rows, w.rows), \
             "warm-cache answers diverged from cold answers"
@@ -275,30 +294,69 @@ def serve_smoke(args) -> None:
 
     # threaded burst: opportunistic micro-batching under backpressure —
     # exercises the dispatcher and records true queue depth (compositions
-    # are timing-dependent, so assert feasibility, not bit equality)
-    dp = Dispatcher(svc, max_batch=8)
+    # are timing-dependent, so assert feasibility, not bit equality).  Any
+    # four consecutive requests of the mix have distinct fuse keys, so with
+    # at most four per batch every batch compiles the same single-request
+    # entries whatever its timing.
+    dp = Dispatcher(svc, max_batch=4)
     try:
         for res in dp.map(reqs):
             assert res.feasible, res.detail
     finally:
         dp.close()
     assert svc.queue_depth_max >= 1
+    t4 = time.perf_counter()
+    log(f"warm_s={t3 - t2:.3f} burst_s={t4 - t3:.3f} "
+        f"entries={svc.cache.compiles}")
 
     # ~1% churn delta: block-local re-solve, then a warm re-query
     n_del = max(1, n // 100)
-    del_ids = [int(x) for x in r.choice(n, n_del, replace=False)]
-    ins_rows = data[r.choice(n, n_del, replace=False)] * np.float32(0.5)
+    del_ids = [int(x) for x in rng.choice(n, n_del, replace=False)]
+    ins_rows = data[rng.choice(n, n_del, replace=False)] * np.float32(0.5)
     ins_attrs = np.zeros((n_del, 2), np.float32)
-    ins_attrs[:, 0] = r.uniform(0.2, 1.0, n_del).astype(np.float32)
-    ins_attrs[:, 1] = r.integers(0, 3, n_del).astype(np.float32)
+    ins_attrs[:, 0] = rng.uniform(0.2, 1.0, n_del).astype(np.float32)
+    ins_attrs[:, 1] = rng.integers(0, 3, n_del).astype(np.float32)
     rep = svc.apply_delta(insert_rows=ins_rows, insert_attrs=ins_attrs,
                           delete_ids=del_ids)
     after = svc.query(reqs[0])
+    t5 = time.perf_counter()
     assert after.feasible, after.detail
 
     npv = _np_exemplar_value(E, after.rows, after.mask)
     rel = abs(npv - after.value) / max(abs(npv), 1e-12)
     status = "PASS" if np.isfinite(after.value) and rel < 1e-3 else "FAIL"
+    return ServeExercise(
+        service=svc, session=st, cold=cold, after=after, delta=rep,
+        ingest_s=t_ingest, cold_s=t2 - t1, warm_s=t3 - t2, burst_s=t4 - t3,
+        delta_s=t5 - t4,
+        recheck={"fp32": npv, "solve": float(after.value),
+                 "rel_gap": float(rel), "status": status})
+
+
+def serve_smoke(args) -> None:
+    """CI-grepable exercise of the selection service without a daemon:
+    :func:`serve_exercise` on a registry dataset, then the ``serve:`` /
+    ``recheck:`` report lines and a validated manifest."""
+    from repro.engine.telemetry import (RunManifest, config_dict,
+                                        config_fingerprint)
+    from repro.serve import round_ladder
+
+    data = np.asarray(datasets.REGISTRY[args.dataset](), np.float32)
+    n, d = data.shape
+    r = np.random.default_rng(args.seed)
+    E = data[r.choice(n, min(args.n_eval, n), replace=False)]
+
+    tracer = (Tracer() if (args.trace_out or args.metrics_out
+                           or args.manifest_out) else None)
+    cfg = TreeConfig(k=args.k, capacity=args.capacity,
+                     algorithm=args.algorithm, eps=args.eps, seed=args.seed,
+                     permutation=args.permutation, engine=args.engine,
+                     hosts=args.hosts, telemetry=tracer)
+    print(f"serve-smoke: n={n} d={d} k={args.k} mu={args.capacity} "
+          f"requests={args.serve_requests} engine={args.engine}")
+    ex = serve_exercise(data, E, cfg, n_requests=args.serve_requests, rng=r,
+                        tracer=tracer)
+    svc, st, after, rep = ex.service, ex.session, ex.after, ex.delta
 
     ladder = round_ladder(st.Mp, args.k, st.mu)
     run = {"n": n, "d": d, "k": args.k, "mu": args.capacity,
@@ -310,10 +368,10 @@ def serve_smoke(args) -> None:
     manifest = RunManifest(config=config_dict(cfg),
                            config_fingerprint=config_fingerprint(cfg),
                            run=run, dtype="fp32")
-    manifest.phases = {"ingest_s": t_ingest, "serve_s": t_serve}
+    manifest.phases = {"ingest_s": ex.ingest_s,
+                       "serve_s": ex.cold_s + ex.warm_s}
     manifest.serve = svc.serve_stats()
-    manifest.recheck = {"fp32": npv, "solve": float(after.value),
-                        "rel_gap": float(rel), "status": status}
+    manifest.recheck = ex.recheck
     for line in format_report(manifest):
         print(line)
     print(f"delta: inserted={rep.inserted} deleted={rep.deleted} "
@@ -328,13 +386,14 @@ def serve_smoke(args) -> None:
     if args.manifest_out:
         manifest.write(args.manifest_out)
     problems = manifest.validate()
-    assert status == "PASS", (npv, after.value, rel)
+    assert ex.recheck["status"] == "PASS", ex.recheck
     print("manifest: OK" if not problems
           else f"manifest: INVALID {problems}")
     assert not problems, problems
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="csn-20k",
                     choices=sorted(datasets.REGISTRY))

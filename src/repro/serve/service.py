@@ -52,7 +52,7 @@ import numpy as np
 from repro.core.constraints import (DynamicKnapsack, DynamicPartitionMatroid,
                                     Intersection, Knapsack, PartitionMatroid,
                                     Unconstrained, check_feasible, from_spec)
-from repro.core.distributed import run_round
+from repro.core.distributed import RoundResult, run_round, upload
 from repro.core.objectives import (ExemplarClustering,
                                    WeightedExemplarClustering)
 from repro.core.partition import n_parts, repartition_rows
@@ -246,15 +246,36 @@ def make_round0_fn(fuse_key):
     unit of the service's solution cache and partial re-solve."""
     k, alg, eps, sig, weighted, _Mp, _mu, _d, a, _n_eval = fuse_key
 
-    def round0(blocks, bmask, keys, eval_set, ew, cparams):
+    def round0(blocks, bmask, keys, eval_set, ew, cparams, attrs):
         obj = _make_obj(eval_set, ew, weighted)
         cons = build_constraint(sig, cparams)
+        # attributes ride beside the features as run_round's out-of-band
+        # ``meta``: no widened (·, d + a) copy of the resident blocks, and
+        # no feature slice of one inside the solve
         res = run_round(obj, blocks, bmask, keys, k=k, alg=alg, eps=eps,
-                        attr_dim=a, constraint=cons)
+                        attr_dim=a, constraint=cons,
+                        meta=attrs if a else None)
         return (res.sol_rows, res.sol_mask, res.values, res.oracle_calls,
                 res.depth)
 
     return round0
+
+
+def _run_round_in_turn(obj, blocks, bmask, keys, **kw) -> RoundResult:
+    """``run_round`` solving one machine at a time (``lax.map``).
+
+    The tail's rounds hold few machines, so nothing is lost by solving
+    them in turn.  Vmapped, a round of nine 1,000 × 3,072 blocks is one
+    105 MiB operand that XLA keeps in VMEM across the greedy loop, and on
+    a TPU v5e (libtpu 0.0.34) that program never returned once the picks
+    spread over the blocks; one 12 MiB block at a time runs.
+    """
+    def one(x):
+        blk, bm, key = x
+        res = run_round(obj, blk[None], bm[None], key[None], **kw)
+        return jax.tree.map(lambda v: v[0], res)
+
+    return RoundResult(*jax.lax.map(one, (blocks, bmask, keys)))
 
 
 def make_tail_fn(fuse_key):
@@ -283,8 +304,8 @@ def make_tail_fn(fuse_key):
             chain, kpart, kalg = jax.random.split(chain, 3)
             blk, bm = repartition_rows(rows_in, mask_in, kpart, m, mu)
             keys = jax.random.split(kalg, m)
-            res = run_round(obj, blk, bm, keys, k=k, alg=alg, eps=eps,
-                            attr_dim=a, constraint=cons)
+            res = _run_round_in_turn(obj, blk, bm, keys, k=k, alg=alg,
+                                     eps=eps, attr_dim=a, constraint=cons)
             (best_rows, best_mask, best_val, total_calls, round_depth,
              _) = _fold_round(
                 res.sol_rows, res.sol_mask, res.values, res.oracle_calls,
@@ -466,20 +487,18 @@ class SelectionService:
         self._keys0 = jax.random.split(kalg, s.Mp)
         self._dev = {}
 
-    def _staged(self, wide: bool):
-        """Device copies of the resident blocks, refreshed when membership
-        moves; unconstrained requests use the narrow (features-only)
-        operand so they never pay for attribute columns."""
+    def _staged(self):
+        """Device copies of the resident features, validity and attribute
+        columns, refreshed when membership moves.  One feature copy serves
+        every request: constrained solves read the attributes beside it."""
         s = self.session
         stamp = (s.generation, s.versions.tobytes())
         if self._dev.get("stamp") != stamp:
-            self._dev = {"stamp": stamp}
-        name = "wide" if wide else "narrow"
-        if name not in self._dev:
-            blocks = (np.concatenate([s.blocks, s.attrs], axis=2)
-                      if wide else s.blocks)
-            self._dev[name] = (jnp.asarray(blocks), jnp.asarray(s.valid))
-        return self._dev[name]
+            self._dev = {}            # release the stale copy before upload
+            self._dev = {"stamp": stamp, "blocks": upload(s.blocks),
+                         "valid": jnp.asarray(s.valid),
+                         "attrs": jnp.asarray(s.attrs)}
+        return self._dev["blocks"], self._dev["valid"], self._dev["attrs"]
 
     # -- request preparation ---------------------------------------------
     def _prepare(self, req: SelectionRequest) -> _Prep:
@@ -552,8 +571,7 @@ class SelectionService:
     def _serve_group(self, fk, items) -> list[SelectionResult]:
         s = self.session
         k, _alg, _eps, sig, _weighted, Mp, _mu, d, a, n_eval = fk
-        wide = a > 0
-        blocks, bmask = self._staged(wide)
+        blocks, bmask, attrs = self._staged()
         gen = s.generation
 
         # --- per-request round-0 solutions: cache → partial → batched miss
@@ -568,12 +586,13 @@ class SelectionService:
             self._sol_cache.move_to_end(ck)        # refresh LRU recency
             changed = np.flatnonzero(ent["versions"] != s.versions)
             if changed.size:
-                self._partial_resolve(fk, prep, ent, changed, blocks, bmask)
+                self._partial_resolve(fk, prep, ent, changed, blocks, bmask,
+                                      attrs)
             else:
                 self.sol_hits += 1
             sols[j] = ent["sols"]
         if misses:
-            self._solve_misses(fk, items, misses, sols, blocks, bmask)
+            self._solve_misses(fk, items, misses, sols, blocks, bmask, attrs)
 
         # --- tail: fold + rounds ≥ 1, batched over the group
         B = _bucket(len(items))
@@ -625,7 +644,8 @@ class SelectionService:
                 detail=detail, solve_depth=int(bdepth[j])))
         return outs
 
-    def _solve_misses(self, fk, items, misses, sols, blocks, bmask) -> None:
+    def _solve_misses(self, fk, items, misses, sols, blocks, bmask,
+                      attrs) -> None:
         """Round 0 for requests with no cached per-machine solutions, one
         fused batched launch; results land in the solution cache."""
         s = self.session
@@ -637,16 +657,17 @@ class SelectionService:
         def build_round0():
             body = make_round0_fn(fk)
 
-            def batched(blocks, bmask, keys, eval_set, ews, cps):
+            def batched(blocks, bmask, keys, eval_set, ews, cps, attrs):
                 def one(x):
                     ew, cp = x
-                    return body(blocks, bmask, keys, eval_set, ew, cp)
+                    return body(blocks, bmask, keys, eval_set, ew, cp, attrs)
                 return jax.lax.map(one, (ews, cps))
             return batched
 
         fn = self.cache.entry("round0", fk, (B, s.Mp), build_round0)
         rrows, rmask, rvals, rcalls, rdepth = fn(blocks, bmask, self._keys0,
-                                                 self.eval_set, ews, cps)
+                                                 self.eval_set, ews, cps,
+                                                 attrs)
         rrows = np.asarray(rrows)
         rmask = np.asarray(rmask)
         rvals = np.asarray(rvals)
@@ -668,7 +689,8 @@ class SelectionService:
             self.tracer.metrics.gauge("serve_sol_cache_entries").set(
                 len(self._sol_cache))
 
-    def _partial_resolve(self, fk, prep, ent, changed, blocks, bmask) -> None:
+    def _partial_resolve(self, fk, prep, ent, changed, blocks, bmask,
+                         attrs) -> None:
         """Re-solve only the machine blocks whose membership version moved
         since this request fingerprint's round-0 solutions were cached,
         then scatter them back — the delta fast path."""
@@ -681,17 +703,22 @@ class SelectionService:
         def build_round0():
             body = make_round0_fn(fk)
 
-            def batched(blocks, bmask, keys, eval_set, ews, cps):
+            def batched(blocks, bmask, keys, eval_set, ews, cps, attrs):
                 def one(x):
                     ew, cp = x
-                    return body(blocks, bmask, keys, eval_set, ew, cp)
+                    return body(blocks, bmask, keys, eval_set, ew, cp, attrs)
                 return jax.lax.map(one, (ews, cps))
             return batched
 
         fn = self.cache.entry("round0", fk, (1, Cp), build_round0)
+        if C < s.Mp:
+            blocks, bmask, keys, attrs = (blocks[idx], bmask[idx],
+                                          self._keys0[idx], attrs[idx])
+        else:                      # every block moved: no gathered copy
+            keys = self._keys0
         rrows, rmask, rvals, rcalls, rdepth = fn(
-            blocks[idx], bmask[idx], self._keys0[idx], self.eval_set,
-            prep.ew[None], prep.cparams[None])
+            blocks, bmask, keys, self.eval_set,
+            prep.ew[None], prep.cparams[None], attrs)
         sr, sm, vv, cc, dp = (np.array(x) for x in ent["sols"])
         sr[changed] = np.asarray(rrows)[0, :C]
         sm[changed] = np.asarray(rmask)[0, :C]
@@ -788,12 +815,10 @@ def offline_solve(session: SessionState, eval_set, req: SelectionRequest, *,
     key = jax.random.PRNGKey(session.seed)
     key1, _kpart, kalg = jax.random.split(key, 3)
     keys0 = jax.random.split(kalg, Mp)
-    blocks = (np.concatenate([session.blocks, session.attrs], axis=2)
-              if a > 0 else session.blocks)
-
     r0 = jax.jit(make_round0_fn(fk))(
-        jnp.asarray(blocks), jnp.asarray(session.valid), keys0,
-        svc.eval_set, jnp.asarray(prep.ew), jnp.asarray(prep.cparams))
+        jnp.asarray(session.blocks), jnp.asarray(session.valid), keys0,
+        svc.eval_set, jnp.asarray(prep.ew), jnp.asarray(prep.cparams),
+        jnp.asarray(session.attrs))
     brows, bmask, bval, bcalls, bdepth = jax.jit(make_tail_fn(fk))(
         *r0, svc.eval_set, jnp.asarray(prep.ew), jnp.asarray(prep.cparams),
         jnp.int32(req.seed), key1)

@@ -46,12 +46,8 @@ def fit_spec(shape: Sequence[int], spec: Sequence[Any],
 
 def _ambient_mesh_shape() -> dict[str, int] | None:
     """Axis sizes of the ambient mesh, or None when no mesh is set."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):   # jax ≥ 0.5
-        am = jax.sharding.get_abstract_mesh()
-        return None if am.empty else dict(am.shape)
-    from jax._src import mesh as _mesh_lib           # jax 0.4.x: `with mesh:`
-    pm = _mesh_lib.thread_resources.env.physical_mesh
-    return None if pm.empty else dict(pm.shape)
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else dict(am.shape)
 
 
 def shard(x: jax.Array, *spec) -> jax.Array:

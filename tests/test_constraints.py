@@ -340,6 +340,7 @@ def test_fused_dispatch_falls_back_for_unfusable_constraints():
     data, obj = _setup(n=64, seed=5)
     attrs = jnp.asarray(_attrs(len(data), seed=5))
     assert _fusable(obj, None, None)
+    assert _fusable(obj, None, attrs)       # attributes without a constraint
     assert _fusable(obj, Knapsack(1.0), attrs)
     assert _fusable(obj, PartitionMatroid((2, 2, 2, 2), col=1), attrs)
     assert _fusable(obj, Intersection((Knapsack(1.0),)), attrs)

@@ -11,7 +11,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _run(code: str):
-    env = dict(os.environ,
+    # children run on the CPU: a chip belongs to the one process that
+    # opened it, so a child must never reach for it
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=SRC)
     r = subprocess.run([sys.executable, "-c", code], env=env,
@@ -49,7 +51,6 @@ from repro.launch.mesh import make_debug_mesh
 from repro import sharding as shd
 from repro.train import optimizer as opt_lib, train_step as ts_lib
 from repro.data.pipeline import DataConfig, SyntheticLM
-_use_mesh = jax.set_mesh if hasattr(jax, 'set_mesh') else (lambda m: m)  # 0.4.x: Mesh is a ctx mgr
 
 cfg = get_config("qwen3-8b").reduced()
 opt_cfg = opt_lib.OptConfig(lr=1e-3, moment_dtype="float32")
@@ -61,7 +62,7 @@ batch = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size, seq_len=32,
 s1, m1 = jax.jit(step)(jax.tree.map(lambda x: x, state), batch)
 
 mesh = make_debug_mesh(2, 2)
-with _use_mesh(mesh):
+with jax.set_mesh(mesh):
     shardings = shd.param_sharding_tree(state, mesh)
     state_sh = jax.device_put(state, shardings)
     tok_sh = jax.device_put(batch["tokens"],
@@ -80,7 +81,6 @@ import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.launch.mesh import make_debug_mesh
 from repro.models import get_model
-_use_mesh = jax.set_mesh if hasattr(jax, 'set_mesh') else (lambda m: m)  # 0.4.x: Mesh is a ctx mgr
 
 cfg = get_config("gemma-2b").reduced()
 m = get_model(cfg)
@@ -89,7 +89,7 @@ toks = jax.random.randint(jax.random.PRNGKey(1), (4, 8), 0, cfg.vocab_size)
 cache = m.init_cache(cfg, 4, 16)
 lp1, c1 = m.prefill(params, cfg, toks, cache)
 mesh = make_debug_mesh(2, 2)
-with _use_mesh(mesh):
+with jax.set_mesh(mesh):
     lp2, c2 = jax.jit(lambda p, t, c: m.prefill(p, cfg, t, c))(params, toks, cache)
 np.testing.assert_allclose(np.asarray(lp1, np.float32),
                            np.asarray(lp2, np.float32), rtol=6e-2, atol=6e-2)

@@ -184,7 +184,10 @@ def test_novel_shape_compiles_exactly_once(world):
 # ---------------------------------------------------------------------------
 
 
-def _delta_args(kind, X):
+def _delta_args(kind, X, st):
+    if kind == "every-machine":            # one deletion in each block
+        return None, None, [int(ids[ok][0])
+                            for ids, ok in zip(st.item_ids, st.valid)]
     rng = np.random.default_rng(77)
     ins = (X[rng.choice(N, 6, replace=False)] * np.float32(0.5),
            np.ascontiguousarray(
@@ -199,19 +202,22 @@ def _delta_args(kind, X):
     return ins[0], ins[1], dels
 
 
-@pytest.mark.parametrize("kind", ["insert", "delete", "mixed"])
+@pytest.mark.parametrize("kind", ["insert", "delete", "mixed",
+                                  "every-machine"])
 @pytest.mark.parametrize("cons", [None, "knapsack:budget=1.5"])
 def test_delta_equals_rebuild(world, kind, cons):
     X, attrs, E, cfg, _st, _svc = world
-    rows, ia, dels = _delta_args(kind, X)
     req = SelectionRequest(k=K, constraint=cons)
 
     # path 1: resident delta (block-local re-solve), then query
     s1 = _fresh_session(X, attrs, cfg)
+    rows, ia, dels = _delta_args(kind, X, s1)
     v1 = SelectionService(s1, E)
     v1.query(req)                          # populate the solution cache
     rep = v1.apply_delta(insert_rows=rows, insert_attrs=ia, delete_ids=dels)
     assert not rep.rebuilt
+    if kind == "every-machine":            # re-solve without a gathered copy
+        assert len(rep.changed_machines) == s1.Mp
     a = v1.query(req)
     if kind != "insert":
         assert v1.partial_resolves >= 1    # deltas touched cached machines
@@ -489,3 +495,23 @@ def test_unbounded_caches_by_default(world):
     assert svc.cache.capacity is None and svc.sol_cache_capacity is None
     assert svc.serve_stats()["cache_evictions"] == 0
     assert svc.serve_stats()["sol_cache_evictions"] == 0
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["plain", "weighted"])
+def test_tail_round_in_turn_equals_vmapped_round(weighted):
+    # the tail solves its rounds one machine at a time; each machine's
+    # answer must be the one the vmapped round gives it
+    from repro.core.distributed import run_round
+    from repro.serve.service import _run_round_in_turn
+    X, _attrs, E = _data()
+    blocks = jnp.asarray(X[:3 * MU].reshape(3, MU, D))
+    bmask = jnp.asarray(np.arange(3 * MU).reshape(3, MU) % 5 != 4)
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    ew = query_relevance_weights(X[7], E)
+    obj = (WeightedExemplarClustering(jnp.asarray(E),
+                                      eval_weights=jnp.asarray(ew))
+           if weighted else ExemplarClustering(jnp.asarray(E)))
+    want = run_round(obj, blocks, bmask, keys, k=K)
+    got = _run_round_in_turn(obj, blocks, bmask, keys, k=K)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -166,6 +166,46 @@ def test_mesh_streaming_identity():
     _assert_identical(resident, streamed)
 
 
+@pytest.mark.parametrize("use_mesh", [False, True], ids=["plain", "mesh"])
+def test_waves_uploaded_in_pieces_identical(monkeypatch, use_mesh):
+    # waves larger than one transfer are uploaded in pieces and joined on
+    # the device; the rows, sharding and result must not change
+    from repro.core import distributed, make_submod_mesh
+    data, obj = _setup(seed=6)
+    mesh = make_submod_mesh() if use_mesh else None
+    cfg = TreeConfig(k=8, capacity=60, seed=2)
+    whole = tree_maximize(obj, ArraySource(data), cfg, mesh=mesh,
+                          wave_machines=4)
+    monkeypatch.setattr(distributed, "MAX_TRANSFER_BYTES", 1000)
+    sizes = []
+    put = jax.device_put
+
+    def spy(x, *a, **kw):
+        sizes.append(np.asarray(x).nbytes)
+        return put(x, *a, **kw)
+
+    monkeypatch.setattr(distributed.jax, "device_put", spy)
+    x = data[:40]
+    sharding = None if mesh is None else jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec("machines"))
+    up = distributed.upload(x, sharding)
+    np.testing.assert_array_equal(np.asarray(up), x)
+    assert len(sizes) > 1 and max(sizes) <= 1000, sizes
+    if sharding is not None:
+        assert up.sharding == sharding
+    pieced = tree_maximize(obj, ArraySource(data), cfg, mesh=mesh,
+                           wave_machines=4)
+    _assert_identical(whole, pieced)
+
+
+def test_upload_guard_within_largest_good_transfer():
+    # the piece size may not grow past the largest host transfer the round
+    # program was seen to run on (see distributed.MAX_TRANSFER_BYTES)
+    from repro.core import distributed
+    assert (0 < distributed.MAX_TRANSFER_BYTES
+            <= distributed.LARGEST_GOOD_TRANSFER_BYTES)
+
+
 def test_host_rounds_rejects_sources():
     data, obj = _setup()
     with pytest.raises(ValueError):

@@ -110,3 +110,25 @@ def test_serve_generates():
     out = greedy_generate(cfg, params, prompt, n_new=5)
     assert out.shape == (2, 5)
     assert bool(jnp.all((out >= 0) & (out < cfg.padded_vocab)))
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    import os
+    from repro.launch.compile_cache import CHECKOUT, enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert path == os.path.join(CHECKOUT, ".jax_cache")
+        assert os.path.isfile(os.path.join(CHECKOUT, "chip_smoke.py"))
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_leaves_the_variable_to_jax(monkeypatch, tmp_path):
+    from repro.launch.compile_cache import enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    prev = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == prev

@@ -472,3 +472,16 @@ def test_profiler_session_noop_without_dir():
         pass
     with profiler_session(""):
         pass
+
+
+def test_profiler_session_fails_when_the_profiler_cannot_start(
+        tmp_path, monkeypatch):
+    import jax
+
+    def refuse(_dir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with profiler_session(str(tmp_path / "prof")):
+            pass
