@@ -66,7 +66,7 @@ from repro.engine.scheduler import (ENGINES, EngineConfig, HostWave,
 from repro.engine.stats import (CheckpointStats, EngineStats, FaultStats,
                                 RoundCheckpoint)
 from repro.engine.telemetry import (MANIFEST_NAME, build_manifest,
-                                    dtype_label, feed_result_metrics)
+                                    dtype_label, feed_result_metrics, span)
 
 PERMUTATIONS = ("dense", "feistel")
 
@@ -107,12 +107,12 @@ class TreeConfig:
     #                                    (source fingerprint, μ, ndev) so
     #                                    reruns start at the knee
     telemetry: Any = None              # repro.engine.telemetry.Tracer, or
-    #                                    None (default): spans from every
-    #                                    engine seam + a RunManifest next to
+    #                                    None (default): records the spans
+    #                                    every engine seam writes to the
+    #                                    profiler, + a RunManifest next to
     #                                    the checkpoints.  Observation only —
     #                                    outputs are bit-identical either
-    #                                    way, and None costs nothing (every
-    #                                    seam guards on `tracer is not None`)
+    #                                    way
 
     def __post_init__(self):
         assert self.capacity > self.k, (
@@ -605,33 +605,53 @@ def _stream_round0(obj, source: GroundSetSource, kpart, kalg, L: int,
         return rows, row_attrs, None
 
     def gather(i: int) -> HostWave | None:
-        """Host side of wave i: source reads + numpy block assembly.
-        Runs on the prefetch thread under the pipelined engine — no JAX."""
-        span = next_span()
-        if span is None:
+        """Host side of wave i: source reads (``wave.read``) + numpy block
+        assembly (``wave.mask``).  Runs on the prefetch thread under the
+        pipelined engine — no device work."""
+        bounds = next_span()
+        if bounds is None:
             return None                                     # machines done
-        w0, w1 = span
+        w0, w1 = bounds
+        last = w1 >= Mp
         idx_w = slot_block(w0, w1)                          # (Wb, cap)
         idx_flat = np.maximum(idx_w, 0).reshape(-1)
         valid = idx_w >= 0
-        if supervisor is None:
-            rows, row_attrs, per_host = gather_rows(idx_flat, wave=i)
-        else:
-            def attempt_fn(attempt: int):
-                hook = (fault_injector.host_hook(i, attempt)
-                        if fault_injector is not None else None)
-                return gather_rows(idx_flat, fault_hook=hook, wave=i)
+        with span("wave.read", tracer=tracer, wave=i):
+            if supervisor is None:
+                rows, row_attrs, per_host = gather_rows(idx_flat, wave=i)
+                dropped = False
+            else:
+                def attempt_fn(attempt: int):
+                    hook = (fault_injector.host_hook(i, attempt)
+                            if fault_injector is not None else None)
+                    return gather_rows(idx_flat, fault_hook=hook, wave=i)
 
-            gathered, dropped = supervisor.gather(
-                i, machines=w1 - w0, rows=int(valid.sum()),
-                attempt_fn=attempt_fn)
-            if dropped:
-                # wave forfeited (Lemma 3.4 budget already checked): its
-                # machines fold as dead downstream — no rows move
-                return HostWave(payload=(None, None, valid, w0, w1, True),
-                                machines=w1 - w0, rows=(w1 - w0) * mu,
-                                bytes_moved=0, per_host_rows=None)
-            rows, row_attrs, per_host = gathered
+                gathered, dropped = supervisor.gather(
+                    i, machines=w1 - w0, rows=int(valid.sum()),
+                    attempt_fn=attempt_fn)
+                if not dropped:
+                    rows, row_attrs, per_host = gathered
+            if narrow and qcols and not dropped:
+                qmeta = source.gather_qmeta(idx_flat)
+        if dropped:
+            # wave forfeited (Lemma 3.4 budget already checked): its
+            # machines fold as dead downstream — no rows move
+            return HostWave(payload=(None, None, valid, w0, w1, True),
+                            machines=w1 - w0, rows=(w1 - w0) * mu,
+                            bytes_moved=0, per_host_rows=None, last=last)
+        with span("wave.mask", tracer=tracer, wave=i):
+            payload = mask_wave(rows, row_attrs,
+                                qmeta if narrow and qcols else None,
+                                valid, w0, w1)
+        return HostWave(payload=payload, machines=w1 - w0,
+                        rows=(w1 - w0) * mu,
+                        bytes_moved=sum(x.nbytes for x in payload[:2]
+                                        if x is not None),
+                        per_host_rows=per_host, last=last)
+
+    def mask_wave(rows, row_attrs, qmeta, valid, w0, w1):
+        """The wave's payload from its gathered rows: machine blocks with
+        padded slots zeroed (plus the narrow path's fp32 meta matrix)."""
         if narrow:
             # narrow wire format: the feature block keeps the storage
             # dtype end-to-end; attrs + per-row dequant params ship as one
@@ -644,17 +664,14 @@ def _stream_round0(obj, source: GroundSetSource, kpart, kalg, L: int,
             if a:
                 cols.append(np.asarray(row_attrs, np.float32))
             if qcols:
-                cols.append(source.gather_qmeta(idx_flat))
+                cols.append(qmeta)
             if cols:
                 meta = np.concatenate(cols, axis=1).reshape(
                     w1 - w0, mu, meta_cols)
                 meta = np.where(valid[..., None], meta, np.float32(0.0))
             else:
                 meta = np.zeros((w1 - w0, mu, 0), np.float32)
-            return HostWave(payload=(feat, meta, valid, w0, w1, False),
-                            machines=w1 - w0, rows=(w1 - w0) * mu,
-                            bytes_moved=feat.nbytes + meta.nbytes,
-                            per_host_rows=per_host)
+            return feat, meta, valid, w0, w1, False
         rows = np.asarray(rows, np.float32)
         if a:
             rows = np.concatenate(
@@ -663,9 +680,7 @@ def _stream_round0(obj, source: GroundSetSource, kpart, kalg, L: int,
         # bit-identical to the device-side jnp.where masking it replaces
         blocks = np.where(valid[..., None],
                           rows.reshape(w1 - w0, mu, d + a), np.float32(0.0))
-        return HostWave(payload=(blocks, None, valid, w0, w1, False),
-                        machines=w1 - w0, rows=(w1 - w0) * mu,
-                        bytes_moved=blocks.nbytes, per_host_rows=per_host)
+        return blocks, None, valid, w0, w1, False
 
     sol_rows, sol_mask = [], []
     carry = [best_rows, best_mask, best_val, total_calls,
@@ -684,21 +699,23 @@ def _stream_round0(obj, source: GroundSetSource, kpart, kalg, L: int,
             # masked solutions contribute nothing to A_1, zero oracle
             # calls — honest accounting) and skip the dispatch entirely
             res = dead_wave_result(w1 - w0, cfg.k, d + a)
-        elif meta_np is None:
-            blocks, bmask = stage_wave_inputs(mesh, blocks_np, valid)
-            res = _dispatch_blocks(obj, blocks, bmask, keys[w0:w1],
-                                   dead[w0:w1], cfg, mesh, attr_dim=a,
-                                   constraint=constraint)
         else:
-            blocks, bmask, meta = stage_wave_inputs(mesh, blocks_np, valid,
-                                                    meta_np)
-            res = _dispatch_blocks(obj, blocks, bmask, keys[w0:w1],
-                                   dead[w0:w1], cfg, mesh, attr_dim=a,
-                                   constraint=constraint, meta=meta)
-        (carry[0], carry[1], carry[2], carry[3], carry[4],
-         v_wave) = _fold_round(
-            res.sol_rows, res.sol_mask, res.values, res.oracle_calls,
-            res.depth, *carry[:5])
+            with span("wave.stage", tracer=tracer, wave=i):
+                staged = stage_wave_inputs(mesh, blocks_np, valid, meta_np)
+                # the upload returns before the copy lands; the round
+                # program waits on these arrays anyway
+                jax.block_until_ready(staged)
+            blocks, bmask, *meta = staged      # meta: narrow waves only
+            with span("wave.dispatch", tracer=tracer, wave=i):
+                res = _dispatch_blocks(obj, blocks, bmask, keys[w0:w1],
+                                       dead[w0:w1], cfg, mesh, attr_dim=a,
+                                       constraint=constraint,
+                                       meta=meta[0] if meta else None)
+        with span("wave.fold", tracer=tracer, wave=i):
+            (carry[0], carry[1], carry[2], carry[3], carry[4],
+             v_wave) = _fold_round(
+                res.sol_rows, res.sol_mask, res.values, res.oracle_calls,
+                res.depth, *carry[:5])
         carry[5] = jnp.maximum(carry[5], v_wave)
         sol_rows.append(res.sol_rows)
         sol_mask.append(res.sol_mask)
@@ -888,113 +905,119 @@ def tree_maximize(
     ckpt_rounds: list[RoundCheckpoint] = []
     tracer = cfg.telemetry
     round_walls: list[float] = []
-    t_run0 = time.perf_counter()
 
-    try:
-        while True:
-            rt0 = time.perf_counter()
-            key, kpart, kalg = jax.random.split(key, 3)
-            if t != 0:
-                n_items = int(_host_scalar(jnp.sum(mask_in.astype(jnp.int32))))
-            L = part_lib.n_parts(n_items, mu)
+    with span("run.run", tracer=tracer) as run_span:
+        try:
+            while True:
+                with span("round.round", tracer=tracer, round=t) as rs:
+                    key, kpart, kalg = jax.random.split(key, 3)
+                    if t != 0:
+                        with span("round.sync", tracer=tracer, round=t):
+                            n_items = int(_host_scalar(
+                                jnp.sum(mask_in.astype(jnp.int32))))
+                    L = part_lib.n_parts(n_items, mu)
 
-            if t == 0 and streaming:
-                # ---- wave-scheduled ingestion: ≤ W·μ rows device-resident
-                machines_per_round.append(L)
-                (best_rows, best_mask, best_val, total_calls, round_depth,
-                 v_best, rows_in, mask_in, ingest,
-                 engine_stats) = _stream_round0(
-                    obj, source, kpart, kalg, L, cfg, mesh, fail_machines,
-                    wave_machines, best_rows, best_mask, best_val,
-                    total_calls, constraint=constraint, attrs_np=attrs_np,
-                    wave_schedule=wave_schedule,
-                    fault_injector=fault_injector)
-                round_values.append(_host_scalar(v_best))
-                depth_per_round.append(int(_host_scalar(round_depth)))
-            else:
-                # ---- partition A_t into L balanced parts (virtual-location)
-                if t == 0:
-                    part = _round0_partition(kpart, n, L, mu, cfg.permutation)
-                    blocks, bmask = part_lib.gather_partition(data, part)
-                else:
-                    blocks, bmask = part_lib.repartition_rows(
-                        rows_in, mask_in, kpart, L, mu)
+                    if t == 0 and streaming:
+                        # ---- wave-scheduled ingestion: ≤ W·μ rows on device
+                        machines_per_round.append(L)
+                        (best_rows, best_mask, best_val, total_calls,
+                         round_depth, v_best, rows_in, mask_in, ingest,
+                         engine_stats) = _stream_round0(
+                            obj, source, kpart, kalg, L, cfg, mesh,
+                            fail_machines, wave_machines, best_rows,
+                            best_mask, best_val, total_calls,
+                            constraint=constraint, attrs_np=attrs_np,
+                            wave_schedule=wave_schedule,
+                            fault_injector=fault_injector)
+                        round_values.append(_host_scalar(v_best))
+                        depth_per_round.append(
+                            int(_host_scalar(round_depth)))
+                    else:
+                        # ---- partition A_t into L balanced parts
+                        if t == 0:
+                            part = _round0_partition(kpart, n, L, mu,
+                                                     cfg.permutation)
+                            blocks, bmask = part_lib.gather_partition(data,
+                                                                      part)
+                        else:
+                            with span("round.repartition", tracer=tracer,
+                                      round=t):
+                                blocks, bmask = part_lib.repartition_rows(
+                                    rows_in, mask_in, kpart, L, mu)
 
-                machines_per_round.append(blocks.shape[0])
-                res = _dispatch_round(obj, blocks, bmask, kalg, t, cfg, mesh,
-                                      fail_machines, attr_dim=a,
-                                      constraint=constraint)
+                        machines_per_round.append(blocks.shape[0])
+                        with span("round.dispatch", tracer=tracer, round=t):
+                            res = _dispatch_round(obj, blocks, bmask, kalg, t,
+                                                  cfg, mesh, fail_machines,
+                                                  attr_dim=a,
+                                                  constraint=constraint)
 
-                (best_rows, best_mask, best_val, total_calls, round_depth,
-                 v_best) = _fold_round(
-                    res.sol_rows, res.sol_mask, res.values, res.oracle_calls,
-                    res.depth, best_rows, best_mask, best_val, total_calls,
-                    jnp.int32(0))
-                round_values.append(_host_scalar(v_best))
-                depth_per_round.append(int(_host_scalar(round_depth)))
+                        (best_rows, best_mask, best_val, total_calls,
+                         round_depth, v_best) = _fold_round(
+                            res.sol_rows, res.sol_mask, res.values,
+                            res.oracle_calls, res.depth, best_rows, best_mask,
+                            best_val, total_calls, jnp.int32(0))
+                        with span("round.sync", tracer=tracer, round=t):
+                            round_values.append(_host_scalar(v_best))
+                            depth_per_round.append(
+                                int(_host_scalar(round_depth)))
 
-                # ---- union of partial solutions = next A (device-resident)
-                rows_in = res.sol_rows.reshape(-1, d + a)
-                mask_in = res.sol_mask.reshape(-1)
-            t += 1
+                        # ---- union of partial solutions = next A (device)
+                        rows_in = res.sol_rows.reshape(-1, d + a)
+                        mask_in = res.sol_mask.reshape(-1)
+                    t += 1
 
-            if cfg.checkpoint_dir:
-                # snapshot on the caller thread (device→host pulls produce
-                # fresh buffers the writer owns outright) ...
-                ts0 = time.perf_counter()
-                snap = (cfg.checkpoint_dir, t, _host_array(rows_in),
-                        _host_array(mask_in), _host_array(best_rows),
-                        _host_array(best_mask), _host_scalar(best_val),
-                        int(_host_scalar(total_calls)), cfg.checkpoint_keep,
-                        cfg.checkpoint_delta_every)
-                if tracer is not None:
-                    tracer.emit("ckpt-snapshot", "ckpt", ts0,
-                                time.perf_counter(), round=t)
-                if writer is not None:
-                    # ... then overlap the serialize+write with round t+1
-                    # (submit's internal barrier drained write t-1 already)
-                    writer.submit(t, *snap)
-                else:
-                    t0 = time.perf_counter()
-                    _save_round(*snap)
-                    dt = time.perf_counter() - t0
-                    if tracer is not None:
-                        tracer.emit("ckpt-write", "ckpt", t0, t0 + dt,
-                                    round=t)
-                    ckpt_rounds.append(RoundCheckpoint(
-                        round=t, write_s=dt, wait_s=dt))
+                    if cfg.checkpoint_dir:
+                        # snapshot on the caller thread (device→host pulls
+                        # produce fresh buffers the writer owns outright) ...
+                        with span("ckpt.snapshot", tracer=tracer, round=t):
+                            snap = (cfg.checkpoint_dir, t,
+                                    _host_array(rows_in),
+                                    _host_array(mask_in),
+                                    _host_array(best_rows),
+                                    _host_array(best_mask),
+                                    _host_scalar(best_val),
+                                    int(_host_scalar(total_calls)),
+                                    cfg.checkpoint_keep,
+                                    cfg.checkpoint_delta_every)
+                        if writer is not None:
+                            # ... then overlap the serialize+write with round
+                            # t+1 (submit's barrier drained write t-1 already)
+                            writer.submit(t, *snap)
+                        else:
+                            with span("ckpt.write", tracer=tracer,
+                                      round=t) as cw:
+                                _save_round(*snap)
+                            dt = cw.t1 - cw.t0
+                            ckpt_rounds.append(RoundCheckpoint(
+                                round=t, write_s=dt, wait_s=dt))
+                    # depth rides on the round span: τ-levels run inside the
+                    # fused launch (device while_loop), so per-level spans
+                    # are reported as the measured ladder length, not host
+                    # timings
+                    rs.args.update(machines=machines_per_round[-1],
+                                   depth=depth_per_round[-1])
+                round_walls.append(rs.t1 - rs.t0)
 
-            rt1 = time.perf_counter()
-            round_walls.append(rt1 - rt0)
-            if tracer is not None:
-                # depth rides on the round span: τ-levels run inside the
-                # fused launch (device while_loop), so per-level spans are
-                # reported as the measured ladder length, not host timings
-                tracer.emit("round", "round", rt0, rt1, round=t - 1,
-                            machines=machines_per_round[-1],
-                            depth=depth_per_round[-1])
-
-            if L == 1:        # that was the final single-machine round
-                break
-            assert t <= r_bound + 1, (
-                f"round bound violated: {t} > {r_bound} (Prop 3.1)")
-    except BaseException:
+                if L == 1:        # that was the final single-machine round
+                    break
+                assert t <= r_bound + 1, (
+                    f"round bound violated: {t} > {r_bound} (Prop 3.1)")
+        except BaseException:
+            if writer is not None:
+                writer.abort()  # drain in-flight write; keep the root cause
+            raise
+        ckpt_stats: CheckpointStats | None = None
         if writer is not None:
-            writer.abort()    # drain in-flight write; keep the root cause
-        raise
-    ckpt_stats: CheckpointStats | None = None
-    if writer is not None:
-        writer.wait()         # final write barrier: resume-complete on disk
-        ckpt_stats = writer.stats()
-    elif cfg.checkpoint_dir:
-        ckpt_stats = CheckpointStats(mode="sync", rounds=ckpt_rounds)
+            writer.wait()       # final write barrier: resume-complete on disk
+            ckpt_stats = writer.stats()
+        elif cfg.checkpoint_dir:
+            ckpt_stats = CheckpointStats(mode="sync", rounds=ckpt_rounds)
 
-    sel_wide = _host_array(best_rows)
-    sel_mask_np = _host_array(best_mask)
-    value = _host_scalar(best_val)
-    t_run1 = time.perf_counter()
-    if tracer is not None:
-        tracer.emit("run", "run", t_run0, t_run1, rounds=t, value=value)
+        sel_wide = _host_array(best_rows)
+        sel_mask_np = _host_array(best_mask)
+        value = _host_scalar(best_val)
+        run_span.args.update(rounds=t, value=value)
     result = _finish_result(
         sel_wide, sel_mask_np, d, a, constraint,
         value=value, rounds=t,
@@ -1003,7 +1026,7 @@ def tree_maximize(
         ingest=ingest, engine_stats=engine_stats,
         checkpoint_stats=ckpt_stats,
         fault_stats=engine_stats.fault_stats if engine_stats else None,
-        round_walls=round_walls, total_wall_s=t_run1 - t_run0,
+        round_walls=round_walls, total_wall_s=run_span.t1 - run_span.t0,
         depth_per_round=depth_per_round,
         solve_depth=sum(depth_per_round))
     if tracer is not None:

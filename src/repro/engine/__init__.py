@@ -20,7 +20,8 @@ Six layers (see each module's docstring):
     checkpoint-overlap record, and the fault/straggler records, surfaced
     on ``TreeResult``.
   * :mod:`repro.engine.telemetry` — the unified observation layer: span
-    tracer over every seam above (Chrome trace / JSONL exporters),
+    helper over every seam above, written to the profiler's trace and to
+    an optional span tracer (Chrome trace exporter),
     labelled metrics registry the stats dataclasses feed, and the
     atomically written ``RunManifest`` + consolidated CLI report
     formatter.
@@ -47,8 +48,8 @@ from repro.engine.stats import (CheckpointStats, EngineStats, FaultEvent,
 from repro.engine.telemetry import (MetricsRegistry, RunManifest, SpanEvent,
                                     Tracer, build_manifest, dtype_label,
                                     feed_result_metrics, format_report,
-                                    profiler_session, read_jsonl_events,
-                                    top_spans, wave_overlap_from_spans)
+                                    profiler_session, span, top_spans,
+                                    wave_overlap_from_spans)
 
 __all__ = [
     "ENGINES", "AsyncCheckpointWriter", "AutotuneCache", "AutotunePlanner",
@@ -63,7 +64,7 @@ __all__ = [
     "clean_stale_tmp", "dtype_label", "feed_result_metrics",
     "format_report", "latest_round_checkpoint", "list_round_checkpoints",
     "load_round_checkpoint", "overlap_from_traces", "overlap_ratio",
-    "profiler_session", "read_jsonl_events", "run_waves", "shape_bound",
-    "snap_down", "suggest_prefetch_depth", "top_spans",
+    "profiler_session", "run_waves", "shape_bound", "snap_down", "span",
+    "suggest_prefetch_depth", "top_spans",
     "wave_overlap_from_spans", "write_round_checkpoint",
 ]

@@ -42,12 +42,12 @@ import os
 import re
 import shutil
 import threading
-import time
 from typing import Any, Callable
 
 import numpy as np
 
 from repro.engine.stats import CheckpointStats, RoundCheckpoint
+from repro.engine.telemetry import span
 
 # ---------------------------------------------------------------------------
 # Round-checkpoint file layout: rotation, crash-safe cleanup, resume lookup.
@@ -255,10 +255,11 @@ def clean_stale_tmp(d: str) -> list[str]:
 class AsyncCheckpointWriter:
     """Background round-checkpoint writer with an explicit write barrier.
 
-    ``tracer`` (if given) gets a ``ckpt``-category span per background
-    write (on the writer thread's own track — Perfetto shows it running
-    under the next round's compute) and per non-trivial barrier wait (on
-    the caller's track — the only checkpoint time the round loop paid).
+    Each background write is a ``ckpt.write`` span (on the writer
+    thread's own track — Perfetto shows it running under the next round's
+    compute) and each barrier wait a ``ckpt.wait`` span (on the caller's
+    track — the only checkpoint time the round loop paid); ``tracer`` (if
+    given) records both.
     """
 
     def __init__(self, write_fn: Callable[..., None], tracer=None):
@@ -276,16 +277,13 @@ class AsyncCheckpointWriter:
         """Wait out the in-flight write; returns the caller's stall time."""
         if self._thread is None:
             return 0.0
-        t0 = time.perf_counter()
-        self._thread.join()
-        t1 = time.perf_counter()
-        stall = t1 - t0
+        with span("ckpt.wait", tracer=self.tracer,
+                  round=self._pending_round) as w:
+            self._thread.join()
+        stall = w.t1 - w.t0
         self._thread = None
         if self._pending_round is not None:
             self._wait_s[self._pending_round] = stall
-            if self.tracer is not None:
-                self.tracer.emit("ckpt-wait", "ckpt", t0, t1,
-                                 round=self._pending_round)
             self._pending_round = None
         return stall
 
@@ -316,17 +314,13 @@ class AsyncCheckpointWriter:
         self.wait()
 
         def work():
-            t0 = time.perf_counter()
-            try:
-                self._write_fn(*args, **kwargs)
-            except BaseException as exc:   # re-raised at the next barrier
-                self._exc = exc
-            finally:
-                t1 = time.perf_counter()
-                self._write_s[round_idx] = t1 - t0
-                if self.tracer is not None:
-                    self.tracer.emit("ckpt-write", "ckpt", t0, t1,
-                                     round=round_idx)
+            with span("ckpt.write", tracer=self.tracer,
+                      round=round_idx) as w:
+                try:
+                    self._write_fn(*args, **kwargs)
+                except BaseException as exc:  # re-raised at the next barrier
+                    self._exc = exc
+            self._write_s[round_idx] = w.t1 - w.t0
 
         self._pending_round = round_idx
         self._order.append(round_idx)

@@ -30,11 +30,12 @@ so the engine's overlap measurements reflect hosts working concurrently.
 from __future__ import annotations
 
 import dataclasses
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import TYPE_CHECKING, Callable, Tuple
 
 import numpy as np
+
+from repro.engine.telemetry import span
 
 if TYPE_CHECKING:  # typing only — keeps repro.engine importable before
     from repro.core.sources import GroundSetSource  # repro.core finishes
@@ -147,10 +148,11 @@ class IngestionPlan:
         a real deployment's RPC to that host would fail), so injected
         errors/latency land per-host, not per-wave.
 
-        ``tracer`` (if given) gets one ``host`` span per host that served
-        rows, on a named ``host-<id>`` track — so a host's gathers line up
-        on one Perfetto lane regardless of which pool thread served them,
-        and host skew within a wave is visible.  ``wave`` labels the spans.
+        Each host that serves rows opens a ``host.host-gather`` span; a
+        ``tracer`` (if given) records it on a named ``host-<id>`` track,
+        so a host's gathers line up on one Perfetto lane regardless of
+        which pool thread served them, and host skew within a wave is
+        visible.  ``wave`` labels the spans.
         """
         idx = np.asarray(idx, np.int64).reshape(-1)
         owner_pos = np.searchsorted(self._los, idx, side="right") - 1
@@ -167,16 +169,14 @@ class IngestionPlan:
             if fault_hook is not None:
                 fault_hook(shard)
             local_idx = idx[hit]
-            t0 = time.perf_counter() if tracer is not None else 0.0
-            if with_attrs:
-                r, a = shard.source.gather_with_attrs(local_idx)
-            else:
-                r, a = shard.source.gather(local_idx), None
-            if tracer is not None:
-                tracer.emit("host-gather", "host", t0, time.perf_counter(),
-                            track=f"host-{shard.host}", host=shard.host,
-                            rows=int(local_idx.size),
-                            **({} if wave is None else {"wave": wave}))
+            with span("host.host-gather", tracer=tracer,
+                      track=f"host-{shard.host}", host=shard.host,
+                      rows=int(local_idx.size),
+                      **({} if wave is None else {"wave": wave})):
+                if with_attrs:
+                    r, a = shard.source.gather_with_attrs(local_idx)
+                else:
+                    r, a = shard.source.gather(local_idx), None
             return pos, hit, r, a
 
         parallel = parallel and len(self.shards) > 1 and all(

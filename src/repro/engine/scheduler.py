@@ -55,8 +55,8 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 
-from repro.engine.stats import (EngineStats, WaveTrace, overlap_from_traces,
-                                overlap_ratio)
+from repro.engine.stats import EngineStats, WaveTrace, overlap_from_traces
+from repro.engine.telemetry import span
 
 ENGINES = ("sync", "pipelined")
 
@@ -91,6 +91,7 @@ class HostWave(NamedTuple):
     rows: int
     bytes_moved: int
     per_host_rows: list[int] | None = None
+    last: bool = False          # no wave follows: the engine asks for none
 
 
 class _Abort(Exception):
@@ -112,18 +113,23 @@ def run_waves(n_waves: int | None,
     wave order) and returns a device value to block on.
 
     ``n_waves=None`` selects open-ended iteration: ``gather`` is called
-    with increasing ``i`` until it returns ``None`` (the adaptive planner
-    deciding widths on the fly cannot know the wave count up front).  With
-    an int, exactly that many waves run and ``gather`` never returns None.
+    with increasing ``i`` until it returns ``None`` or a wave marked
+    ``last`` (the adaptive planner deciding widths on the fly cannot know
+    the wave count up front).  With an int, exactly that many waves run
+    and ``gather`` never returns None.
 
     ``on_trace`` (if given) receives each completed :class:`WaveTrace` on
     the caller thread, in wave order, *before* the next solve starts —
     the autotuner's feedback point.
 
-    ``tracer`` (a :class:`repro.engine.telemetry.Tracer`, or None) gets a
-    gather span and a solve span per wave — emitted from the thread that
-    did the work, so producer and consumer land on separate tracks — plus
-    ``stall`` spans for semaphore-block / queue-wait backpressure.
+    Every wave opens a ``wave.gather`` span around ``gather`` and a
+    ``wave.solve`` span around ``solve`` and the block on its result
+    (``wave.block``), each on the thread that did the work, so producer
+    and consumer land on separate profiler lines and Tracer tracks; the
+    pipelined engine adds ``stall.sem-block`` / ``stall.queue-wait``
+    spans for backpressure.  ``tracer`` (a
+    :class:`repro.engine.telemetry.Tracer`, or None) records them too.
+    The waves' ``WaveTrace`` stamps are the spans' own readings.
     Telemetry is observation only: the engine's scheduling decisions and
     outputs are identical with or without it.
     """
@@ -132,9 +138,10 @@ def run_waves(n_waves: int | None,
     return _run_pipelined(n_waves, gather, solve, cfg, on_trace, tracer)
 
 
-def _block(x) -> None:
-    if x is not None:
-        jax.block_until_ready(x)
+def _block(x, tracer, wave: int) -> None:
+    with span("wave.block", tracer=tracer, wave=wave):
+        if x is not None:
+            jax.block_until_ready(x)
 
 
 def _finalize(engine: str, cfg: EngineConfig, traces: list[WaveTrace],
@@ -142,8 +149,7 @@ def _finalize(engine: str, cfg: EngineConfig, traces: list[WaveTrace],
     g = sum(t.gather_s for t in traces)
     s = sum(t.solve_s for t in traces)
     # overlap is recomputed from the waves' t_start/t_end timestamps (the
-    # reconstruction a trace-file consumer performs); the pre-timestamp
-    # formula survives as EngineStats.overlap_ratio_legacy for cross-check
+    # reconstruction a trace-file consumer performs)
     span_wall, span_overlap = overlap_from_traces(traces)
     return EngineStats(
         engine=engine, hosts=cfg.hosts, waves=len(traces), wall_s=wall_s,
@@ -160,27 +166,27 @@ def _run_sync(n_waves, gather, solve, cfg, on_trace, tracer=None
     t_start = time.perf_counter()
     i = 0
     while n_waves is None or i < n_waves:
-        t0 = time.perf_counter()
-        hw = gather(i)
+        with span("wave.gather", tracer=tracer, wave=i) as g:
+            hw = gather(i)
+            if hw is not None:
+                g.args.update(machines=hw.machines, rows=hw.rows,
+                              bytes=hw.bytes_moved)
         if hw is None:
             assert n_waves is None, f"gather({i}) returned None mid-count"
             break
-        t1 = time.perf_counter()
-        _block(solve(i, hw.payload))
-        t2 = time.perf_counter()
-        if tracer is not None:
-            tracer.emit("gather", "wave", t0, t1, wave=i,
-                        machines=hw.machines, rows=hw.rows,
-                        bytes=hw.bytes_moved)
-            tracer.emit("solve", "wave", t1, t2, wave=i,
-                        machines=hw.machines)
+        with span("wave.solve", tracer=tracer, wave=i,
+                  machines=hw.machines) as s:
+            _block(solve(i, hw.payload), tracer, i)
         traces.append(WaveTrace(
             wave=i, machines=hw.machines, rows=hw.rows,
-            bytes_moved=hw.bytes_moved, gather_s=t1 - t0, solve_s=t2 - t1,
-            per_host_rows=hw.per_host_rows, t_start=t0, t_end=t2))
+            bytes_moved=hw.bytes_moved, gather_s=g.t1 - g.t0,
+            solve_s=s.t1 - s.t0, per_host_rows=hw.per_host_rows,
+            t_start=g.t0, t_end=s.t1))
         if on_trace is not None:
             on_trace(traces[-1])
         i += 1
+        if hw.last:
+            break
     return _finalize("sync", cfg, traces,
                      time.perf_counter() - t_start, max_live=1)
 
@@ -246,30 +252,29 @@ def _run_pipelined(n_waves, gather, solve, cfg, on_trace, tracer=None
                 # the consumer only after its payload reached the device.
                 # Time spent blocked on the semaphore is the producer-side
                 # stall — the device is the bottleneck while it grows.
-                ts0 = time.perf_counter()
-                if not gauge.acquire(abort):
+                with span("stall.sem-block", tracer=tracer, wave=i,
+                          side="producer") as sb:
+                    acquired = gauge.acquire(abort)
+                if not acquired:
                     raise _Abort
-                t0 = time.perf_counter()
-                stall = t0 - ts0
-                hw = gather(i)
-                t1 = time.perf_counter()
-                dt = t1 - t0
+                stall = sb.t1 - sb.t0
+                with span("wave.gather", tracer=tracer, wave=i) as g:
+                    hw = gather(i)
+                    if hw is not None:
+                        g.args.update(machines=hw.machines, rows=hw.rows,
+                                      bytes=hw.bytes_moved)
                 if hw is None:
                     assert n_waves is None, f"gather({i}) None mid-count"
                     gauge.release()
                     break
                 if tracer is not None:
-                    if stall > 0.0:
-                        tracer.emit("sem-block", "stall", ts0, t0, wave=i,
-                                    side="producer")
                     tracer.metrics.histogram(
                         "scheduler.stall_s", side="producer").observe(stall)
-                    tracer.emit("gather", "wave", t0, t1, wave=i,
-                                machines=hw.machines, rows=hw.rows,
-                                bytes=hw.bytes_moved)
-                if not _put((i, hw, dt, (t0, t1, stall))):
+                if not _put((i, hw, g.t1 - g.t0, (g.t0, g.t1, stall))):
                     raise _Abort
                 i += 1
+                if hw.last:
+                    break
             _put((_DONE, None, 0.0, _IDLE))
         except _Abort:
             pass
@@ -288,34 +293,30 @@ def _run_pipelined(n_waves, gather, solve, cfg, on_trace, tracer=None
             # consumer-side stall: waiting for the producer to deliver the
             # next gathered wave — the gather is the bottleneck while it
             # grows (for wave 0 this is the unavoidable pipeline fill, g0)
-            tw0 = time.perf_counter()
-            i, hw, gather_s, (g0, g1, p_stall) = out.get()
-            tw1 = time.perf_counter()
+            with span("stall.queue-wait", tracer=tracer, wave=expect,
+                      side="consumer") as qw:
+                i, hw, gather_s, (g0, g1, p_stall) = out.get()
             if i is _FAILED:
                 raise exc_slot[0]
             if i is _DONE:
                 break
             assert i == expect, f"wave order broke: got {i}, want {expect}"
-            t1 = time.perf_counter()
-            handle = solve(i, hw.payload)
-            # payload is on device once solve returns — free its buffer
-            # credit so the producer may start gathering the wave after next
-            gauge.release()
-            _block(handle)
-            t2 = time.perf_counter()
+            wait = qw.t1 - qw.t0
+            with span("wave.solve", tracer=tracer, wave=i,
+                      machines=hw.machines) as s:
+                handle = solve(i, hw.payload)
+                # payload is on device once solve returns — free its buffer
+                # credit so the producer may gather the wave after next
+                gauge.release()
+                _block(handle, tracer, i)
             if tracer is not None:
-                if tw1 > tw0:
-                    tracer.emit("queue-wait", "stall", tw0, tw1, wave=i,
-                                side="consumer")
                 tracer.metrics.histogram(
-                    "scheduler.stall_s", side="consumer").observe(tw1 - tw0)
-                tracer.emit("solve", "wave", t1, t2, wave=i,
-                            machines=hw.machines)
+                    "scheduler.stall_s", side="consumer").observe(wait)
             traces.append(WaveTrace(
                 wave=i, machines=hw.machines, rows=hw.rows,
                 bytes_moved=hw.bytes_moved, gather_s=gather_s,
-                solve_s=t2 - t1, per_host_rows=hw.per_host_rows,
-                t_start=g0, t_end=t2, stall_s=p_stall + (tw1 - tw0)))
+                solve_s=s.t1 - s.t0, per_host_rows=hw.per_host_rows,
+                t_start=g0, t_end=s.t1, stall_s=p_stall + wait))
             if on_trace is not None:
                 on_trace(traces[-1])
             expect += 1

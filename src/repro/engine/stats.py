@@ -67,15 +67,6 @@ class EngineStats:
     #                             when the engine predates timestamped traces)
 
     @property
-    def overlap_ratio_legacy(self) -> float:
-        """The pre-timestamp formula, from the engine's measured whole-run
-        ``wall_s``.  Kept as a cross-check on the span-derived ratio: the
-        measured wall includes loop overhead outside any wave span, so
-        ``wall_s ≥ span_wall_s`` and legacy ≤ span-based, with the gap
-        bounded by (loop overhead)/Σgather."""
-        return overlap_ratio(self.gather_s, self.solve_s, self.wall_s)
-
-    @property
     def width_trajectory(self) -> list[int]:
         """Machines per dispatched wave, in wave order — the autoscaler's
         decision record (constant under the fixed-W policy)."""
@@ -97,7 +88,6 @@ class EngineStats:
             "solve_s": round(self.solve_s, 4),
             "bytes_moved": self.bytes_moved,
             "overlap_ratio": round(self.overlap_ratio, 4),
-            "overlap_ratio_legacy": round(self.overlap_ratio_legacy, 4),
             "span_wall_s": round(self.span_wall_s, 4),
             "stall_s": round(sum(t.stall_s for t in self.traces), 4),
             "max_in_flight": self.max_in_flight,

@@ -5,33 +5,44 @@ Every engine layer used to report through its own ad-hoc accounting
 plus one-off CLI print lines, so a single round-0 run could never be seen
 as one timeline.  This module is the one event stream they all feed:
 
-  * :class:`Tracer` — thread-safe begin/end **spans** (monotonic
-    wall-clock, per-thread tracks, category, structured attrs) and
-    instant events, emitted from every seam the engine already owns:
-    wave gather/solve on both scheduler engines (producer + consumer
-    threads), per-host planner gathers, fault retries/hedges/evictions,
-    autotuner rung decisions, async checkpoint snapshot/serialize/write,
-    and rounds t ≥ 1.
+  * :class:`span` — the one span helper every program seam uses.  It
+    always writes a ``jax.profiler.TraceAnnotation`` named
+    ``<cat>.<name>``, so the program's spans sit in any active profile on
+    the device trace's clock, and it records the same span in a
+    :class:`Tracer` when one is given.  Names: ``wave.gather`` (``read``,
+    ``mask``), ``wave.solve`` (``stage``, ``dispatch``, ``fold``,
+    ``block``), ``stall.sem-block``, ``stall.queue-wait``,
+    ``host.host-gather``, ``round.round`` (``repartition``, ``dispatch``,
+    ``sync``), ``ckpt.snapshot``, ``ckpt.write``, ``run.run``, and on the
+    serving path ``serve.submit``, ``serve.drain``, ``serve.reply``,
+    ``serve.prepare``, ``serve.group``, ``serve.round0`` (``.fetch``,
+    ``.partial``), ``serve.tail`` (``.stack``, ``.upload``, ``.fetch``),
+    ``serve.check`` and ``serve.delta``.
+  * :class:`Tracer` — thread-safe **spans** (monotonic wall-clock,
+    per-thread tracks, category, structured attrs) and instant events:
+    the spans above when attached, plus the fault supervisor's retries,
+    hedges and evictions, autotuner rung decisions and the async
+    checkpoint writer.
   * :class:`MetricsRegistry` — counters / gauges / histograms with
     labels; :func:`feed_result_metrics` projects the existing stats
     dataclasses onto it, so those dataclasses are *views* over the same
     per-wave trace stream the spans are cut from
     (``WaveTrace.t_start/t_end/stall_s`` carry the raw timestamps).
   * Exporters — Chrome ``trace_event`` JSON (loads in Perfetto /
-    ``chrome://tracing``, one track per thread and per ingestion host),
-    a JSONL structured-event log, and the :class:`RunManifest` (config
-    fingerprint, source fingerprint, dtype, width trajectory, fault
-    replay signature, final value, bytes, per-phase walls) written
-    atomically next to the checkpoints.
+    ``chrome://tracing``, one track per thread and per ingestion host)
+    and the :class:`RunManifest` (config fingerprint, source fingerprint,
+    dtype, width trajectory, fault replay signature, final value, bytes,
+    per-phase walls) written atomically next to the checkpoints.
   * :func:`profiler_session` — optional ``jax.profiler`` start/stop
-    bracketing keyed by a ``--profile-dir`` flag.
+    bracketing keyed by a ``--profile-dir`` flag; its trace carries the
+    spans above beside the device's operations.
 
-Design contract: telemetry is **observation only**.  Instrumented seams
-guard every emission with ``if tracer is not None`` so the no-telemetry
-path allocates nothing new on the hot path, and an instrumented run is
-bit-identical to an uninstrumented one (pinned by
-tests/test_telemetry.py) — spans record when work happened, never change
-what work happens.
+Design contract: telemetry is **observation only**.  A span never changes
+what work happens, and an instrumented run is bit-identical to an
+uninstrumented one (pinned by tests/test_telemetry.py and
+tests/test_program_spans.py).  With no Tracer attached and no profile
+active a span costs one annotation (about a microsecond); seams guard
+every other emission with ``if tracer is not None``.
 """
 from __future__ import annotations
 
@@ -44,6 +55,7 @@ import threading
 import time
 from typing import Any, Callable, Iterator
 
+import jax
 import numpy as np
 
 from repro.engine.stats import (CheckpointStats, EngineStats, FaultStats,
@@ -155,18 +167,6 @@ class Tracer:
         with self._lock:
             self.events.append(ev)
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str, *, track: str | None = None,
-             **args) -> Iterator[dict]:
-        """Begin/end span around a block; yields the args dict so the
-        block may attach results (e.g. rows gathered) before the end."""
-        t0 = time.perf_counter()
-        try:
-            yield args
-        finally:
-            self.emit(name, cat, t0, time.perf_counter(), track=track,
-                      **args)
-
     # -- accessors ---------------------------------------------------------
     def spans(self, cat: str | None = None,
               name: str | None = None) -> list[SpanEvent]:
@@ -205,35 +205,46 @@ class Tracer:
                                       "schema_version": SCHEMA_VERSION,
                                       "created_unix": self.created_unix}})
 
-    def export_jsonl(self, path: str) -> None:
-        """Structured-event log: one JSON object per line — track
-        declarations first, then events in start order.  Round-trips via
-        :func:`read_jsonl_events`."""
-        lines = [json.dumps({"type": "meta",
-                             "schema_version": SCHEMA_VERSION,
-                             "created_unix": self.created_unix})]
-        lines += [json.dumps({"type": "track", "tid": tid, "name": name})
-                  for tid, name in sorted(self.track_names().items())]
-        with self._lock:
-            events = list(self.events)
-        for e in sorted(events, key=lambda e: e.t0):
-            lines.append(json.dumps({
-                "type": "span" if e.phase == "X" else "instant",
-                "name": e.name, "cat": e.cat, "tid": e.track,
-                "t0": e.t0 - self.epoch, "t1": e.t1 - self.epoch,
-                "args": e.args}))
-        _atomic_write_text(path, "\n".join(lines) + "\n")
 
+class span:
+    """One block of program work, timed for the profiler and the Tracer.
 
-def read_jsonl_events(path: str) -> list[dict]:
-    """Parse an :meth:`Tracer.export_jsonl` file back into dicts."""
-    out = []
-    with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if line:
-                out.append(json.loads(line))
-    return out
+    ``name`` is ``"<cat>.<name>"`` (``"wave.gather"``,
+    ``"serve.round0.fetch"``).  The block always runs inside a
+    ``jax.profiler.TraceAnnotation`` of that name, so it lands in any
+    active profile on the device trace's clock; the profiler keeps host
+    events per thread, so a span is opened and closed on the thread that
+    does the work.  With no profile active the annotation costs about a
+    microsecond.  With a ``tracer`` the block is also recorded there as
+    span ``name`` of category ``cat``, with ``args`` on the Tracer side
+    only (the block may add to ``.args`` before it ends).  ``t0``/``t1``
+    hold the block's ``perf_counter`` bounds once it has ended, for
+    callers whose own records must agree with the span exactly.
+    """
+    __slots__ = ("name", "tracer", "track", "args", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, *, tracer: Tracer | None = None,
+                 track: str | None = None, **args):
+        self.name = name
+        self.tracer = tracer
+        self.track = track
+        self.args = args
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "span":
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self.tracer is not None:
+            cat, _, name = self.name.partition(".")
+            self.tracer.emit(name, cat, self.t0, self.t1, track=self.track,
+                             **self.args)
+        return False
 
 
 # ---------------------------------------------------------------------------
@@ -549,7 +560,6 @@ def build_manifest(cfg, result, *, n: int, d: int, dtype_label: str,
             "stall_s": sum(t.stall_s for t in es.traces),
             "bytes_moved": es.bytes_moved,
             "overlap_ratio": es.overlap_ratio,
-            "overlap_ratio_legacy": es.overlap_ratio_legacy,
             "max_in_flight": es.max_in_flight,
             "width_trajectory": es.width_trajectory,
             "distinct_shapes": es.distinct_shapes,

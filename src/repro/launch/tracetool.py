@@ -3,9 +3,8 @@
     PYTHONPATH=src python -m repro.launch.tracetool trace.json \
         [--manifest run_manifest.json] [--limit N] [--tol 1e-6]
 
-Reads a trace exported by :class:`repro.engine.telemetry.Tracer` — either
-the Chrome ``trace_event`` JSON (``--trace-out``) or the JSONL
-structured-event log — and prints:
+Reads the Chrome ``trace_event`` JSON exported by
+:class:`repro.engine.telemetry.Tracer` (``--trace-out``) and prints:
 
   * an event census (spans / instants / tracks),
   * the top span groups by total seconds (``top_spans``),
@@ -28,48 +27,31 @@ import argparse
 import json
 import sys
 
-from repro.engine.telemetry import (RunManifest, SpanEvent, read_jsonl_events,
-                                    top_spans, wave_overlap_from_spans)
+from repro.engine.telemetry import (RunManifest, SpanEvent, top_spans,
+                                    wave_overlap_from_spans)
 
 
 def load_trace(path: str) -> tuple[list[SpanEvent], dict[int, str]]:
-    """Parse either trace format back into ``SpanEvent`` records.
+    """Parse a Chrome trace back into ``SpanEvent`` records.
 
-    Chrome export stores microseconds relative to the trace epoch, JSONL
-    stores seconds; both come back as seconds here.  Unrounded floats
-    survive the JSON round-trip exactly, so overlap reconstruction holds
-    to float precision.
+    The export stores microseconds relative to the trace epoch; they come
+    back as seconds here.  Unrounded floats survive the JSON round-trip,
+    so overlap reconstruction holds to float precision.
     """
     with open(path) as f:
-        text = f.read()
-    try:
-        doc = json.loads(text)
-    except json.JSONDecodeError:
-        doc = None                  # multiple lines → JSONL
+        doc = json.load(f)
     events: list[SpanEvent] = []
     tracks: dict[int, str] = {}
-    if isinstance(doc, dict) and "traceEvents" in doc:
-        for rec in doc["traceEvents"]:
-            ph = rec.get("ph")
-            if ph == "M" and rec.get("name") == "thread_name":
-                tracks[rec["tid"]] = rec["args"]["name"]
-            elif ph in ("X", "i"):
-                t0 = rec["ts"] / 1e6
-                t1 = t0 + (rec.get("dur", 0.0) / 1e6)
-                events.append(SpanEvent(
-                    name=rec["name"], cat=rec.get("cat", ""), t0=t0, t1=t1,
-                    track=rec["tid"], phase=ph, args=rec.get("args", {})))
-    else:
-        for rec in read_jsonl_events(path):
-            kind = rec.get("type")
-            if kind == "track":
-                tracks[rec["tid"]] = rec["name"]
-            elif kind in ("span", "instant"):
-                events.append(SpanEvent(
-                    name=rec["name"], cat=rec["cat"], t0=rec["t0"],
-                    t1=rec["t1"], track=rec["tid"],
-                    phase="X" if kind == "span" else "i",
-                    args=rec.get("args", {})))
+    for rec in doc["traceEvents"]:
+        ph = rec.get("ph")
+        if ph == "M" and rec.get("name") == "thread_name":
+            tracks[rec["tid"]] = rec["args"]["name"]
+        elif ph in ("X", "i"):
+            t0 = rec["ts"] / 1e6
+            t1 = t0 + (rec.get("dur", 0.0) / 1e6)
+            events.append(SpanEvent(
+                name=rec["name"], cat=rec.get("cat", ""), t0=t0, t1=t1,
+                track=rec["tid"], phase=ph, args=rec.get("args", {})))
     return events, tracks
 
 
@@ -85,7 +67,7 @@ def span_overlap(events: list[SpanEvent]) -> tuple[float, float, int]:
 
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("trace", help="Chrome trace JSON or JSONL event log")
+    ap.add_argument("trace", help="Chrome trace JSON (--trace-out)")
     ap.add_argument("--manifest", default=None,
                     help="RunManifest JSON to validate and cross-check "
                          "against the trace")

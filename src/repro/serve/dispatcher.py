@@ -21,6 +21,14 @@ equal each other exactly.
 Queue depth at each drain is recorded on the service
 (``note_queue_depth``) so the `serve` telemetry track and the manifest's
 ``queue_depth_max`` reflect real backpressure, not a synthetic load test.
+
+Spans (:class:`repro.engine.telemetry.span`, on the profiler's clock and
+in the service's Tracer when it has one): ``serve.submit`` around each
+enqueue on the caller's thread; on the worker, ``serve.drain`` from the
+moment a request is taken off the queue until the last answer of its
+slice is handed back (the idle wait for work is outside it), with one
+``serve.reply`` per request, in queue order.  A request's queueing time
+is the start of the drain that replies to it less the end of its submit.
 """
 from __future__ import annotations
 
@@ -28,6 +36,7 @@ import queue
 import threading
 from concurrent.futures import Future
 
+from repro.engine.telemetry import span
 from repro.serve.service import SelectionRequest, SelectionService
 
 
@@ -59,7 +68,8 @@ class Dispatcher:
 
     def submit(self, req: SelectionRequest) -> Future:
         fut: Future = Future()
-        self._q.put((req, fut))
+        with span("serve.submit", tracer=self.service.tracer):
+            self._q.put((req, fut))
         return fut
 
     def map(self, requests) -> list:
@@ -88,20 +98,25 @@ class Dispatcher:
         return batch, stopped
 
     def _run(self) -> None:
+        tracer = self.service.tracer
         while True:
             item = self._q.get()
             if item is self._stop:
                 return
-            batch, stopped = self._drain(item)
-            self.service.note_queue_depth(len(batch) + self._q.qsize())
-            reqs = [r for r, _f in batch]
-            try:
-                results = self.service.serve(reqs)
-                for (_r, fut), res in zip(batch, results):
-                    fut.set_result(res)
-            except BaseException as exc:   # surface to every waiter
-                for _r, fut in batch:
-                    if not fut.done():
-                        fut.set_exception(exc)
+            with span("serve.drain", tracer=tracer) as dr:
+                batch, stopped = self._drain(item)
+                dr.args["batch"] = len(batch)
+                self.service.note_queue_depth(len(batch) + self._q.qsize())
+                reqs = [r for r, _f in batch]
+                try:
+                    results = self.service.serve(reqs)
+                    for (_r, fut), res in zip(batch, results):
+                        with span("serve.reply", tracer=tracer):
+                            fut.set_result(res)
+                except BaseException as exc:   # surface to every waiter
+                    for _r, fut in batch:
+                        if not fut.done():
+                            with span("serve.reply", tracer=tracer):
+                                fut.set_exception(exc)
             if stopped:
                 return

@@ -42,7 +42,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from typing import Any
 
 import jax
@@ -57,7 +56,7 @@ from repro.core.objectives import (ExemplarClustering,
                                    WeightedExemplarClustering)
 from repro.core.partition import n_parts, repartition_rows
 from repro.core.tree import _fold_round
-from repro.engine.telemetry import Histogram
+from repro.engine.telemetry import Histogram, span
 from repro.serve.session import SessionState
 
 
@@ -328,12 +327,13 @@ class CompileCache:
     """Jitted solve entries with trace accounting and LRU eviction.
 
     ``entry`` returns the jitted callable for (kind, fuse key, bucket),
-    building + jitting it on first use.  A Python-side counter increments
-    *inside* the traced body — it fires exactly when JAX traces (first
-    call per shape signature) and never on cached executions, so
-    ``compiles`` is a direct retrace probe: steady-state serving must
-    leave it flat, and tests pin that rather than inferring it from
-    timings.
+    building + jitting it on first use, named ``serve_<kind>`` so the
+    device trace shows ``jit_serve_round0`` / ``jit_serve_tail``.  A
+    Python-side counter increments *inside* the traced body — it fires
+    exactly when JAX traces (first call per shape signature) and never
+    on cached executions, so ``compiles`` is a direct retrace probe:
+    steady-state serving must leave it flat, and tests pin that rather
+    than inferring it from timings.
 
     ``capacity`` bounds the entry count: every ``entry`` hit refreshes
     recency, and inserts past the bound evict the least-recently-used
@@ -381,6 +381,7 @@ class CompileCache:
             self._trace_counts[_key] = self._trace_counts.get(_key, 0) + 1
             return _inner(*operands)
 
+        counted.__name__ = counted.__qualname__ = f"serve_{kind}"
         fn = jax.jit(counted)
         self._fns[key] = fn
         while self.capacity is not None and len(self._fns) > self.capacity:
@@ -433,6 +434,17 @@ class SelectionService:
     amplify that into a different (equally valid) coreset — so batching
     trades the cross-composition bit-pin for fused-launch throughput
     while keeping feasibility and value accuracy.
+
+    Each ``serve`` call opens spans (:class:`repro.engine.telemetry.span`,
+    on the profiler's clock, and in ``tracer`` when given):
+    ``serve.prepare`` over the slice, then per fuse-key group
+    ``serve.group`` holding the round-0 launch for cache misses
+    (``serve.round0``, ended by a block on its outputs, then
+    ``serve.round0.fetch`` pulling its solutions to the host, or
+    ``serve.round0.partial`` after a delta) and the tail:
+    ``serve.tail.stack`` (host stacking of the group's solutions and
+    operands), ``serve.tail.upload``, ``serve.tail`` (the program, ended
+    by a block), ``serve.tail.fetch`` and ``serve.check``.
     """
 
     def __init__(self, session: SessionState, eval_set, *,
@@ -541,14 +553,17 @@ class SelectionService:
         self._sync_geometry()
         results: list[SelectionResult | None] = [None] * len(requests)
         groups: dict[tuple, list[tuple[int, _Prep]]] = {}
-        for i, req in enumerate(requests):
-            prep = self._prepare(req)
-            groups.setdefault(prep.fuse_key, []).append((i, prep))
+        with span("serve.prepare", tracer=self.tracer,
+                  requests=len(requests)):
+            for i, req in enumerate(requests):
+                prep = self._prepare(req)
+                groups.setdefault(prep.fuse_key, []).append((i, prep))
         for fk, items in groups.items():
-            t0 = time.perf_counter()
-            outs = self._serve_group(fk, items)
-            t1 = time.perf_counter()
-            lat = t1 - t0
+            with span("serve.group", tracer=self.tracer, track="serve",
+                      batch=len(items), k=fk[0],
+                      constraint=str(fk[3][0])) as grp:
+                outs = self._serve_group(fk, items)
+            lat = grp.t1 - grp.t0
             for (i, prep), out in zip(items, outs):
                 out.latency_s = lat
                 out.batch_size = len(items)
@@ -557,9 +572,6 @@ class SelectionService:
             self.requests_served += len(items)
             self.batches += 1
             if self.tracer is not None:
-                self.tracer.emit("request-batch", "serve", t0, t1,
-                                 track="serve", batch=len(items),
-                                 k=fk[0], constraint=str(fk[3][0]))
                 m = self.tracer.metrics
                 m.counter("serve_requests").inc(len(items))
                 m.counter("serve_batches").inc()
@@ -597,14 +609,15 @@ class SelectionService:
         # --- tail: fold + rounds ≥ 1, batched over the group
         B = _bucket(len(items))
         pad = lambda arrs: np.stack(arrs + [arrs[-1]] * (B - len(arrs)))
-        sol_rows = pad([np.asarray(sv[0]) for sv in sols])
-        sol_mask = pad([np.asarray(sv[1]) for sv in sols])
-        values = pad([np.asarray(sv[2]) for sv in sols])
-        calls = pad([np.asarray(sv[3]) for sv in sols])
-        depths = pad([np.asarray(sv[4]) for sv in sols])
-        ews = pad([p.ew for _i, p in items])
-        cps = pad([p.cparams for _i, p in items])
-        seeds = pad([np.int32(p.req.seed) for _i, p in items])
+        with span("serve.tail.stack", tracer=self.tracer):
+            operands = tuple(pad([np.asarray(sv[f]) for sv in sols])
+                             for f in range(5)) + (
+                pad([p.ew for _i, p in items]),
+                pad([p.cparams for _i, p in items]),
+                pad([np.int32(p.req.seed) for _i, p in items]))
+        with span("serve.tail.upload", tracer=self.tracer):
+            operands = jax.block_until_ready(jax.device_put(operands))
+        sol_rows, sol_mask, values, calls, depths, ews, cps, seeds = operands
 
         def build_tail():
             body = make_tail_fn(fk)
@@ -620,28 +633,28 @@ class SelectionService:
             return batched
 
         fn = self.cache.entry("tail", fk, B, build_tail)
-        brows, bmasks, bvals, bcalls, bdepth = fn(
-            sol_rows, sol_mask, values, calls, depths,
-            self.eval_set, ews, cps, seeds, self._key1)
-        brows = np.asarray(brows)
-        bmasks = np.asarray(bmasks)
-        bvals = np.asarray(bvals)
-        bcalls = np.asarray(bcalls)
-        bdepth = np.asarray(bdepth)
+        with span("serve.tail", tracer=self.tracer, batch=B):
+            out = jax.block_until_ready(fn(
+                sol_rows, sol_mask, values, calls, depths,
+                self.eval_set, ews, cps, seeds, self._key1))
+        with span("serve.tail.fetch", tracer=self.tracer):
+            brows, bmasks, bvals, bcalls, bdepth = (np.asarray(x)
+                                                    for x in out)
 
         outs = []
-        for j, (_i, prep) in enumerate(items):
-            rows_w, mask = brows[j], bmasks[j]
-            rows, attrs = rows_w[:, :d], rows_w[:, d:]
-            ok, detail = check_feasible(prep.cons_static, attrs, mask)
-            self.last_value = float(bvals[j])
-            self.last_calls = int(bcalls[j])
-            self.last_rounds = len(round_ladder(Mp, k, s.mu))
-            self.last_depth = int(bdepth[j])
-            outs.append(SelectionResult(
-                rows=rows, attrs=attrs, mask=mask, value=float(bvals[j]),
-                oracle_calls=int(bcalls[j]), feasible=bool(ok),
-                detail=detail, solve_depth=int(bdepth[j])))
+        with span("serve.check", tracer=self.tracer):
+            for j, (_i, prep) in enumerate(items):
+                rows_w, mask = brows[j], bmasks[j]
+                rows, attrs = rows_w[:, :d], rows_w[:, d:]
+                ok, detail = check_feasible(prep.cons_static, attrs, mask)
+                self.last_value = float(bvals[j])
+                self.last_calls = int(bcalls[j])
+                self.last_rounds = len(round_ladder(Mp, k, s.mu))
+                self.last_depth = int(bdepth[j])
+                outs.append(SelectionResult(
+                    rows=rows, attrs=attrs, mask=mask, value=float(bvals[j]),
+                    oracle_calls=int(bcalls[j]), feasible=bool(ok),
+                    detail=detail, solve_depth=int(bdepth[j])))
         return outs
 
     def _solve_misses(self, fk, items, misses, sols, blocks, bmask,
@@ -665,14 +678,12 @@ class SelectionService:
             return batched
 
         fn = self.cache.entry("round0", fk, (B, s.Mp), build_round0)
-        rrows, rmask, rvals, rcalls, rdepth = fn(blocks, bmask, self._keys0,
-                                                 self.eval_set, ews, cps,
-                                                 attrs)
-        rrows = np.asarray(rrows)
-        rmask = np.asarray(rmask)
-        rvals = np.asarray(rvals)
-        rcalls = np.asarray(rcalls)
-        rdepth = np.asarray(rdepth)
+        with span("serve.round0", tracer=self.tracer, batch=B):
+            out = jax.block_until_ready(fn(blocks, bmask, self._keys0,
+                                           self.eval_set, ews, cps, attrs))
+        with span("serve.round0.fetch", tracer=self.tracer):
+            rrows, rmask, rvals, rcalls, rdepth = (np.asarray(x)
+                                                   for x in out)
         for b, j in enumerate(misses):
             prep = items[j][1]
             sv = (rrows[b], rmask[b], rvals[b], rcalls[b], rdepth[b])
@@ -711,20 +722,21 @@ class SelectionService:
             return batched
 
         fn = self.cache.entry("round0", fk, (1, Cp), build_round0)
-        if C < s.Mp:
-            blocks, bmask, keys, attrs = (blocks[idx], bmask[idx],
-                                          self._keys0[idx], attrs[idx])
-        else:                      # every block moved: no gathered copy
-            keys = self._keys0
-        rrows, rmask, rvals, rcalls, rdepth = fn(
-            blocks, bmask, keys, self.eval_set,
-            prep.ew[None], prep.cparams[None], attrs)
-        sr, sm, vv, cc, dp = (np.array(x) for x in ent["sols"])
-        sr[changed] = np.asarray(rrows)[0, :C]
-        sm[changed] = np.asarray(rmask)[0, :C]
-        vv[changed] = np.asarray(rvals)[0, :C]
-        cc[changed] = np.asarray(rcalls)[0, :C]
-        dp[changed] = np.asarray(rdepth)[0, :C]
+        with span("serve.round0.partial", tracer=self.tracer, machines=C):
+            if C < s.Mp:
+                blocks, bmask, keys, attrs = (blocks[idx], bmask[idx],
+                                              self._keys0[idx], attrs[idx])
+            else:                  # every block moved: no gathered copy
+                keys = self._keys0
+            rrows, rmask, rvals, rcalls, rdepth = fn(
+                blocks, bmask, keys, self.eval_set,
+                prep.ew[None], prep.cparams[None], attrs)
+            sr, sm, vv, cc, dp = (np.array(x) for x in ent["sols"])
+            sr[changed] = np.asarray(rrows)[0, :C]
+            sm[changed] = np.asarray(rmask)[0, :C]
+            vv[changed] = np.asarray(rvals)[0, :C]
+            cc[changed] = np.asarray(rcalls)[0, :C]
+            dp[changed] = np.asarray(rdepth)[0, :C]
         ent["sols"] = (sr, sm, vv, cc, dp)
         ent["versions"] = s.versions.copy()
         self.partial_resolves += 1
@@ -735,20 +747,17 @@ class SelectionService:
     # -- ground-set deltas -------------------------------------------------
     def apply_delta(self, insert_rows=None, delete_ids=None,
                     insert_attrs=None):
-        t0 = time.perf_counter()
-        rep = self.session.apply_delta(insert_rows=insert_rows,
-                                       delete_ids=delete_ids,
-                                       insert_attrs=insert_attrs)
-        self.deltas += 1
-        self.delta_changed += len(rep.changed_machines)
-        self.rebuilds += int(rep.rebuilt)
-        self._sync_geometry()
-        if self.tracer is not None:
-            self.tracer.emit("delta", "serve", t0, time.perf_counter(),
-                             track="serve", inserted=rep.inserted,
-                             deleted=rep.deleted,
-                             changed=len(rep.changed_machines),
-                             rebuilt=rep.rebuilt)
+        with span("serve.delta", tracer=self.tracer, track="serve") as sp:
+            rep = self.session.apply_delta(insert_rows=insert_rows,
+                                           delete_ids=delete_ids,
+                                           insert_attrs=insert_attrs)
+            self.deltas += 1
+            self.delta_changed += len(rep.changed_machines)
+            self.rebuilds += int(rep.rebuilt)
+            self._sync_geometry()
+            sp.args.update(inserted=rep.inserted, deleted=rep.deleted,
+                           changed=len(rep.changed_machines),
+                           rebuilt=rep.rebuilt)
         return rep
 
     def note_queue_depth(self, depth: int) -> None:

@@ -369,7 +369,8 @@ def ingest(source, cfg: TreeConfig, *, attrs: np.ndarray | None = None,
             if dropped:
                 return HostWave(payload=(None, None, None, valid, w0, w1),
                                 machines=w1 - w0, rows=(w1 - w0) * mu,
-                                bytes_moved=0, per_host_rows=None)
+                                bytes_moved=0, per_host_rows=None,
+                                last=w1 >= Mp)
             rows, row_attrs, per_host = gathered
         wire_bytes = np.asarray(rows).nbytes + (
             np.asarray(row_attrs).nbytes if row_attrs is not None else 0)
@@ -388,7 +389,8 @@ def ingest(source, cfg: TreeConfig, *, attrs: np.ndarray | None = None,
             am = np.zeros((w1 - w0, mu, 0), np.float32)
         return HostWave(payload=(feat, am, idx_w, valid, w0, w1),
                         machines=w1 - w0, rows=(w1 - w0) * mu,
-                        bytes_moved=wire_bytes, per_host_rows=per_host)
+                        bytes_moved=wire_bytes, per_host_rows=per_host,
+                        last=w1 >= Mp)
 
     blocks = np.zeros((Mp, mu, d), np.float32)
     attr_blk = np.zeros((Mp, mu, a), np.float32)
