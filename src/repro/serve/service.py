@@ -396,6 +396,12 @@ class CompileCache:
         return fn
 
 
+def _device_bytes_limit() -> int | None:
+    """The default device's memory limit, where its backend reports one."""
+    stats = jax.local_devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
 def _bucket(n: int) -> int:
     """Pad counts to powers of two so batch sizes hit few distinct shapes."""
     b = 1
@@ -435,16 +441,26 @@ class SelectionService:
     trades the cross-composition bit-pin for fused-launch throughput
     while keeping feasibility and value accuracy.
 
+    Round-0 solutions stay on the device: the solution cache holds each
+    request's per-machine arrays as device arrays, ``Mp·k·(d+a)·4`` bytes
+    of HBM an entry (plus ``Mp·(k+12)`` bytes of mask, value, call and
+    depth columns), and the tail program stacks a group's entries inside
+    its own ``jit``.  Besides ``sol_cache_capacity`` entries, the cache
+    keeps its bytes within half of the device's ``bytes_limit`` where the
+    backend reports one, evicting least recently used entries first.
+
     Each ``serve`` call opens spans (:class:`repro.engine.telemetry.span`,
     on the profiler's clock, and in ``tracer`` when given):
     ``serve.prepare`` over the slice, then per fuse-key group
     ``serve.group`` holding the round-0 launch for cache misses
     (``serve.round0``, ended by a block on its outputs, then
-    ``serve.round0.fetch`` pulling its solutions to the host, or
-    ``serve.round0.partial`` after a delta) and the tail:
-    ``serve.tail.stack`` (host stacking of the group's solutions and
-    operands), ``serve.tail.upload``, ``serve.tail`` (the program, ended
-    by a block), ``serve.tail.fetch`` and ``serve.check``.
+    ``serve.round0.fetch`` slicing each request's solutions into the
+    cache on the device, or ``serve.round0.partial`` after a delta) and
+    the tail: ``serve.tail.stack`` (host padding of the group's small
+    per-request operands: query weights, constraint parameters, seeds),
+    ``serve.tail.upload``, ``serve.tail`` (the program, which stacks the
+    solutions, ended by a block), ``serve.tail.fetch`` and
+    ``serve.check``.
     """
 
     def __init__(self, session: SessionState, eval_set, *,
@@ -470,6 +486,9 @@ class SelectionService:
         assert sol_cache_capacity is None or sol_cache_capacity >= 1, (
             sol_cache_capacity)
         self.sol_evictions = 0
+        self._sol_bytes = 0
+        limit = _device_bytes_limit()
+        self._sol_bytes_cap = limit // 2 if limit else None
         self._dev: dict[str, Any] = {}
         self._geom: tuple | None = None
         self.requests_served = 0
@@ -501,16 +520,19 @@ class SelectionService:
 
     def _staged(self):
         """Device copies of the resident features, validity and attribute
-        columns, refreshed when membership moves.  One feature copy serves
-        every request: constrained solves read the attributes beside it."""
+        columns, refreshed when membership moves, and of the eval set.  One
+        feature copy serves every request: constrained solves read the
+        attributes beside it."""
         s = self.session
         stamp = (s.generation, s.versions.tobytes())
         if self._dev.get("stamp") != stamp:
             self._dev = {}            # release the stale copy before upload
             self._dev = {"stamp": stamp, "blocks": upload(s.blocks),
                          "valid": jnp.asarray(s.valid),
-                         "attrs": jnp.asarray(s.attrs)}
-        return self._dev["blocks"], self._dev["valid"], self._dev["attrs"]
+                         "attrs": jnp.asarray(s.attrs),
+                         "eval_set": jnp.asarray(self.eval_set)}
+        d = self._dev
+        return d["blocks"], d["valid"], d["attrs"], d["eval_set"]
 
     # -- request preparation ---------------------------------------------
     def _prepare(self, req: SelectionRequest) -> _Prep:
@@ -583,7 +605,7 @@ class SelectionService:
     def _serve_group(self, fk, items) -> list[SelectionResult]:
         s = self.session
         k, _alg, _eps, sig, _weighted, Mp, _mu, d, a, n_eval = fk
-        blocks, bmask, attrs = self._staged()
+        blocks, bmask, attrs, eval_set = self._staged()
         gen = s.generation
 
         # --- per-request round-0 solutions: cache → partial → batched miss
@@ -599,44 +621,45 @@ class SelectionService:
             changed = np.flatnonzero(ent["versions"] != s.versions)
             if changed.size:
                 self._partial_resolve(fk, prep, ent, changed, blocks, bmask,
-                                      attrs)
+                                      attrs, eval_set)
             else:
                 self.sol_hits += 1
             sols[j] = ent["sols"]
         if misses:
-            self._solve_misses(fk, items, misses, sols, blocks, bmask, attrs)
+            self._solve_misses(fk, items, misses, sols, blocks, bmask, attrs,
+                               eval_set)
 
-        # --- tail: fold + rounds ≥ 1, batched over the group
+        # --- tail: fold + rounds ≥ 1, batched over the group.  The cached
+        # solutions enter as one tuple per request, the bucket padded by
+        # reference, and are stacked inside the program.
         B = _bucket(len(items))
-        pad = lambda arrs: np.stack(arrs + [arrs[-1]] * (B - len(arrs)))
+        pad = lambda arrs: arrs + [arrs[-1]] * (B - len(arrs))
         with span("serve.tail.stack", tracer=self.tracer):
-            operands = tuple(pad([np.asarray(sv[f]) for sv in sols])
-                             for f in range(5)) + (
-                pad([p.ew for _i, p in items]),
-                pad([p.cparams for _i, p in items]),
-                pad([np.int32(p.req.seed) for _i, p in items]))
+            small = tuple(np.stack(pad(arrs)) for arrs in (
+                [p.ew for _i, p in items],
+                [p.cparams for _i, p in items],
+                [np.int32(p.req.seed) for _i, p in items]))
         with span("serve.tail.upload", tracer=self.tracer):
-            operands = jax.block_until_ready(jax.device_put(operands))
-        sol_rows, sol_mask, values, calls, depths, ews, cps, seeds = operands
+            ews, cps, seeds = jax.block_until_ready(jax.device_put(small))
 
         def build_tail():
             body = make_tail_fn(fk)
 
-            def batched(srows, smask, vals, cls, dps, eval_set, ews, cps,
-                        seeds, key1):
+            def batched(sols, eval_set, ews, cps, seeds, key1):
+                stacked = tuple(jnp.stack([sv[f] for sv in sols])
+                                for f in range(5))
+
                 def one(x):
                     sr, sm, v, c, dp, ew, cp, sd = x
                     return body(sr, sm, v, c, dp, eval_set, ew, cp, sd,
                                 key1)
-                return jax.lax.map(one, (srows, smask, vals, cls, dps,
-                                         ews, cps, seeds))
+                return jax.lax.map(one, stacked + (ews, cps, seeds))
             return batched
 
         fn = self.cache.entry("tail", fk, B, build_tail)
         with span("serve.tail", tracer=self.tracer, batch=B):
             out = jax.block_until_ready(fn(
-                sol_rows, sol_mask, values, calls, depths,
-                self.eval_set, ews, cps, seeds, self._key1))
+                tuple(pad(sols)), eval_set, ews, cps, seeds, self._key1))
         with span("serve.tail.fetch", tracer=self.tracer):
             brows, bmasks, bvals, bcalls, bdepth = (np.asarray(x)
                                                     for x in out)
@@ -658,9 +681,15 @@ class SelectionService:
         return outs
 
     def _solve_misses(self, fk, items, misses, sols, blocks, bmask,
-                      attrs) -> None:
+                      attrs, eval_set) -> None:
         """Round 0 for requests with no cached per-machine solutions, one
-        fused batched launch; results land in the solution cache."""
+        fused batched launch; results land in the solution cache.
+
+        Each miss's entry is its own device slice of the batched output
+        (a copy, so evicting it frees its HBM and pins no batch buffer):
+        ``Mp·k·(d+a)·4`` bytes of solution rows plus ``Mp·(k+12)`` bytes
+        of masks, values, call counts and depths, 100.8 MB at
+        Mp = 164, k = 50, d + a = 3,074.  Nothing crosses to the host."""
         s = self.session
         B = _bucket(len(misses))
         pad = lambda arrs: np.stack(arrs + [arrs[-1]] * (B - len(arrs)))
@@ -680,31 +709,41 @@ class SelectionService:
         fn = self.cache.entry("round0", fk, (B, s.Mp), build_round0)
         with span("serve.round0", tracer=self.tracer, batch=B):
             out = jax.block_until_ready(fn(blocks, bmask, self._keys0,
-                                           self.eval_set, ews, cps, attrs))
+                                           eval_set, ews, cps, attrs))
         with span("serve.round0.fetch", tracer=self.tracer):
-            rrows, rmask, rvals, rcalls, rdepth = (np.asarray(x)
-                                                   for x in out)
-        for b, j in enumerate(misses):
-            prep = items[j][1]
-            sv = (rrows[b], rmask[b], rvals[b], rcalls[b], rdepth[b])
-            self._sol_cache[(fk, prep.fp, s.generation)] = {
-                "versions": s.versions.copy(), "sols": sv}
+            per_req = jax.block_until_ready(
+                [tuple(x[b] for x in out) for b in range(len(misses))])
+        for j, sv in zip(misses, per_req):
+            ck = (fk, items[j][1].fp, s.generation)
+            old = self._sol_cache.pop(ck, None)   # a repeat within the group
+            if old is not None:
+                self._sol_bytes -= old["bytes"]
+            nbytes = sum(x.nbytes for x in sv)
+            self._sol_cache[ck] = {"versions": s.versions.copy(),
+                                   "sols": sv, "bytes": nbytes}
+            self._sol_bytes += nbytes
             sols[j] = sv
-        while (self.sol_cache_capacity is not None
-               and len(self._sol_cache) > self.sol_cache_capacity):
-            self._sol_cache.popitem(last=False)
+        while self._sol_cache and (
+                (self.sol_cache_capacity is not None
+                 and len(self._sol_cache) > self.sol_cache_capacity)
+                or (self._sol_bytes_cap is not None
+                    and self._sol_bytes > self._sol_bytes_cap)):
+            _ck, old = self._sol_cache.popitem(last=False)
+            self._sol_bytes -= old["bytes"]
             self.sol_evictions += 1
             if self.tracer is not None:
                 self.tracer.metrics.counter("serve_sol_cache_evictions").inc()
         if self.tracer is not None:
-            self.tracer.metrics.gauge("serve_sol_cache_entries").set(
-                len(self._sol_cache))
+            m = self.tracer.metrics
+            m.gauge("serve_sol_cache_entries").set(len(self._sol_cache))
+            m.gauge("serve_sol_cache_bytes").set(self._sol_bytes)
 
     def _partial_resolve(self, fk, prep, ent, changed, blocks, bmask,
-                         attrs) -> None:
+                         attrs, eval_set) -> None:
         """Re-solve only the machine blocks whose membership version moved
         since this request fingerprint's round-0 solutions were cached,
-        then scatter them back — the delta fast path."""
+        then scatter them into the cached device arrays — the delta fast
+        path."""
         s = self.session
         C = int(changed.size)
         Cp = min(_bucket(C), s.Mp)
@@ -728,16 +767,11 @@ class SelectionService:
                                               self._keys0[idx], attrs[idx])
             else:                  # every block moved: no gathered copy
                 keys = self._keys0
-            rrows, rmask, rvals, rcalls, rdepth = fn(
-                blocks, bmask, keys, self.eval_set,
-                prep.ew[None], prep.cparams[None], attrs)
-            sr, sm, vv, cc, dp = (np.array(x) for x in ent["sols"])
-            sr[changed] = np.asarray(rrows)[0, :C]
-            sm[changed] = np.asarray(rmask)[0, :C]
-            vv[changed] = np.asarray(rvals)[0, :C]
-            cc[changed] = np.asarray(rcalls)[0, :C]
-            dp[changed] = np.asarray(rdepth)[0, :C]
-        ent["sols"] = (sr, sm, vv, cc, dp)
+            out = fn(blocks, bmask, keys, eval_set,
+                     prep.ew[None], prep.cparams[None], attrs)
+            ent["sols"] = jax.block_until_ready(tuple(
+                old.at[changed].set(new[0, :C])
+                for old, new in zip(ent["sols"], out)))
         ent["versions"] = s.versions.copy()
         self.partial_resolves += 1
         if self.tracer is not None:
@@ -788,6 +822,7 @@ class SelectionService:
             "sol_cache_hits": self.sol_hits,
             "sol_cache_entries": len(self._sol_cache),
             "sol_cache_evictions": self.sol_evictions,
+            "sol_cache_bytes": self._sol_bytes,
             "sol_cache_capacity": self.sol_cache_capacity,
             "partial_resolves": self.partial_resolves,
             "deltas": self.deltas,

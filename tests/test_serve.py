@@ -4,6 +4,8 @@ compile cache never retraces, query reweighting, feasibility, telemetry.
 Everything here runs against one small resident session (n=120, μ=12,
 Mp=10) so the per-fuse-key compiles are paid once per module.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -515,3 +517,102 @@ def test_tail_round_in_turn_equals_vmapped_round(weighted):
     got = _run_round_in_turn(obj, blocks, bmask, keys, k=K)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# ---------------------------------------------------------------------------
+# round-0 solutions stay on the device
+# ---------------------------------------------------------------------------
+
+
+def _cached_arrays(svc):
+    return [x for ent in svc._sol_cache.values() for x in ent["sols"]]
+
+
+def test_sol_cache_holds_device_arrays(world):
+    X, attrs, E, cfg, _st, _svc = world
+    s = _fresh_session(X, attrs, cfg)
+    svc = SelectionService(s, E)
+    req = SelectionRequest(k=K, constraint="knapsack:budget=1.5")
+    svc.query(req)                                   # miss
+    assert svc.serve_stats()["sol_cache_entries"] == 1
+    assert all(isinstance(x, jax.Array) for x in _cached_arrays(svc))
+    svc.query(dataclasses.replace(req, seed=7))      # hit
+    assert svc.sol_hits == 1
+    assert all(isinstance(x, jax.Array) for x in _cached_arrays(svc))
+    svc.apply_delta(delete_ids=[int(s.item_ids[0][s.valid[0]][0])])
+    svc.query(req)                                   # partial re-solve
+    assert svc.partial_resolves == 1
+    assert all(isinstance(x, jax.Array) for x in _cached_arrays(svc))
+
+
+@pytest.mark.parametrize("cons", [None, "knapsack:budget=1.5"])
+def test_bucket1_hit_and_miss_equal_offline(world, cons):
+    X, attrs, E, cfg, _st, _svc = world
+    s = _fresh_session(X, attrs, cfg)
+    svc = SelectionService(s, E)
+    req = SelectionRequest(k=K, constraint=cons, query=X[21], seed=5)
+    ref = offline_solve(s, E, req)
+    miss = svc.query(req)
+    hit = svc.query(req)
+    assert svc.sol_hits == 1
+    for got in (miss, hit):
+        assert got.value == ref.value
+        assert np.array_equal(got.rows, ref.rows)
+        assert np.array_equal(got.mask, ref.mask)
+        assert got.oracle_calls == ref.oracle_calls
+
+
+@pytest.mark.parametrize("n", [2, 3, 4])
+def test_bucketed_hit_equals_filling_miss(world, n):
+    # across buckets only the composition pins bits: the same group served
+    # from the cache must give the bits of the group that filled it
+    X, attrs, E, cfg, _st, _svc = world
+    s = _fresh_session(X, attrs, cfg)
+    svc = SelectionService(s, E)
+    reqs = [SelectionRequest(k=K, query=X[30 + i], seed=i) for i in range(n)]
+    misses = svc.serve(reqs)
+    assert svc.sol_hits == 0
+    hits = svc.serve(reqs)
+    assert svc.sol_hits == n
+    for a, b in zip(misses, hits):
+        assert a.value == b.value
+        assert np.array_equal(a.rows, b.rows)
+        assert np.array_equal(a.mask, b.mask)
+        assert a.oracle_calls == b.oracle_calls
+
+
+def test_sol_cache_byte_bound_evicts_lru(world, monkeypatch):
+    from repro.serve import service as service_mod
+    X, attrs, E, cfg, _st, _svc = world
+    s = _fresh_session(X, attrs, cfg)
+    reqs = [SelectionRequest(k=K, constraint=f"knapsack:budget={b}")
+            for b in (1.1, 1.2, 1.3)]
+    probe = SelectionService(s, E)
+    assert probe.serve_stats()["sol_cache_bytes"] == 0
+    probe.query(reqs[0])
+    entry = probe.serve_stats()["sol_cache_bytes"]
+    assert entry == sum(x.nbytes for x in _cached_arrays(probe))
+    assert entry >= s.Mp * K * (D + 2) * 4
+    # half of the reported limit holds two entries, not three
+    monkeypatch.setattr(service_mod, "_device_bytes_limit",
+                        lambda: 5 * entry)
+    tracer = Tracer()
+    svc = SelectionService(s, E, tracer=tracer)
+    for r in reqs:
+        svc.query(r)
+    stats = svc.serve_stats()
+    assert stats["sol_cache_capacity"] is None
+    assert stats["sol_cache_entries"] == 2
+    assert stats["sol_cache_evictions"] == 1
+    assert stats["sol_cache_bytes"] == 2 * entry
+    gauges = tracer.metrics.snapshot()["gauges"]
+    assert [v for k, v in gauges.items()
+            if k.startswith("serve_sol_cache_bytes")] == [2 * entry]
+    # the least recently used entry went: the first request misses again,
+    # the last one still hits
+    hits = svc.sol_hits
+    svc.query(reqs[2])
+    assert svc.sol_hits == hits + 1
+    svc.query(reqs[0])
+    assert svc.sol_hits == hits + 1
+    assert svc.serve_stats()["sol_cache_evictions"] == 2
