@@ -1,7 +1,8 @@
 """Bring-up smoke test: the selection system's main path on a TPU.
 
     python chip_smoke.py             # one chip: kernels, batch job, serving
-    python chip_smoke.py --chips 4   # four chips: the batch job on a
+    python chip_smoke.py --chips 4 [--wave-machines 800]
+                                     # four chips: the batch job on a
                                      # 4-device mesh against one device
 
 One process drives the chip (a child would find it taken).  Phases run in
@@ -250,7 +251,8 @@ def batch_data(n: int, seed: int):
     return data, E
 
 
-def tree_run(data, E, mesh, seed: int, comp: Compiles, label: str):
+def tree_run(data, E, mesh, seed: int, comp: Compiles, label: str,
+             wave_machines: int = WAVE_MACHINES):
     import jax.numpy as jnp
     import numpy as np
 
@@ -262,7 +264,7 @@ def tree_run(data, E, mesh, seed: int, comp: Compiles, label: str):
     obj = ExemplarClustering(jnp.asarray(E))
     cfg = TreeConfig(k=K, capacity=MU, algorithm="greedy", seed=seed,
                      engine="pipelined",
-                     capacity_bytes=WAVE_MACHINES * MU * D * 4)
+                     capacity_bytes=wave_machines * MU * D * 4)
     t0 = time.perf_counter()
     # tree_maximize hands back host arrays: the wall below ends after the
     # device finished (every round also pulls its best value to the host)
@@ -313,23 +315,26 @@ def batch_phase(args, devs, comp: Compiles) -> None:
 
 
 def mesh_phase(args, devs, comp: Compiles) -> None:
-    """Four devices against one, same config, same process."""
+    """Four devices against one, same config, same process: the four
+    split each wave of ``--wave-machines``; one device takes waves of one
+    device's share (the answer does not depend on the wave width)."""
     from repro.core import make_submod_mesh
 
     n = args.n
     if n != 1_000_000:
         print(f"batch: n cut from 1000000 to {n}", flush=True)
     data, E = batch_data(n, args.seed)
+    W = args.wave_machines
     r4 = tree_run(data, E, make_submod_mesh(devs[:4]), args.seed, comp,
-                  "4-chip")
+                  "4-chip", W)
     peaks = [_mem(dv) for dv in devs[:4]]
     print(f"batch[4-chip]: peak_bytes_in_use per device={peaks}", flush=True)
     r1 = tree_run(data, E, make_submod_mesh(devs[:1]), args.seed, comp,
-                  "1-chip")
+                  "1-chip", W // 4)
     rel = _rel(r4.value, r1.value)
     print(f"batch: 4-chip vs 1-chip value rel={rel:.3e}", flush=True)
     assert rel <= 1e-5, (r4.value, r1.value)
-    share = WAVE_MACHINES // 4 * MU * D * 4     # one device's part of a wave
+    share = W // 4 * MU * D * 4                # one device's part of a wave
     assert min(peaks) >= share, ("machine blocks did not spread", peaks)
 
 
@@ -381,6 +386,11 @@ def main() -> int:
                     help="4: run only the batch job, on a 4-device mesh "
                          "and on one device")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--wave-machines", type=int, default=WAVE_MACHINES,
+                    help="with --chips 4: machines per round-0 wave; the "
+                         "four chips split each wave (800: 200 machines, "
+                         "2.46 GB, a chip) and the one-chip run takes "
+                         "waves of one chip's share")
     ap.add_argument("--n", type=int, default=1_000_000,
                     help="batch ground-set rows (cut only if host memory "
                          "or time force it)")

@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import algorithms
+from repro.engine.telemetry import span
 
 class RoundResult(NamedTuple):
     sol_rows: jax.Array   # (M, k, d)
@@ -170,6 +171,23 @@ def dead_wave_result(machines: int, k: int, width: int) -> RoundResult:
         depth=jnp.zeros((machines,), jnp.int32))
 
 
+@functools.lru_cache(maxsize=None)
+def _resharder(spec: NamedSharding):
+    return jax.jit(lambda x: x, out_shardings=spec)
+
+
+def _place(x, spec: NamedSharding):
+    """``x`` laid out as ``spec``.  An array already spread over the
+    mesh's devices in another layout (the union between rounds, which the
+    repartition leaves replicated) is resharded by a program on those
+    devices: ``jax.device_put`` would fetch a replicated array to the host
+    to slice it."""
+    if (isinstance(x, jax.Array) and x.sharding.device_set == spec.device_set
+            and not x.sharding.is_equivalent_to(spec, x.ndim)):
+        return _resharder(spec)(x)
+    return jax.device_put(x, spec)
+
+
 def shard_round_inputs(mesh: Mesh, blocks, bmask, keys, meta=None):
     """Place round inputs with the machine axis sharded over the mesh.
 
@@ -177,11 +195,8 @@ def shard_round_inputs(mesh: Mesh, blocks, bmask, keys, meta=None):
     grows to a 4-tuple so it shards under the same machine layout.
     """
     spec = NamedSharding(mesh, P("machines"))
-    out = (jax.device_put(blocks, spec), jax.device_put(bmask, spec),
-           jax.device_put(keys, spec))
-    if meta is None:
-        return out
-    return out + (jax.device_put(meta, spec),)
+    arrays = (blocks, bmask, keys) + (() if meta is None else (meta,))
+    return tuple(_place(a, spec) for a in arrays)
 
 
 # On a TPU v5e (libtpu 0.0.34) the round program halted the core
@@ -215,7 +230,8 @@ def upload(x, sharding=None) -> jax.Array:
     return jax.make_array_from_single_device_arrays(x.shape, sharding, shards)
 
 
-def stage_wave_inputs(mesh: Mesh | None, blocks_np, bmask_np, meta_np=None):
+def stage_wave_inputs(mesh: Mesh | None, blocks_np, bmask_np, meta_np=None,
+                      tracer=None, wave: int | None = None):
     """Host→device staging of one ingestion wave's gathered buffers.
 
     The async engine produces waves as host numpy (gather runs on a
@@ -227,10 +243,40 @@ def stage_wave_inputs(mesh: Mesh | None, blocks_np, bmask_np, meta_np=None):
     dead and the engine may release their in-flight credit (the
     backpressure accounting in :mod:`repro.engine.scheduler`).
 
+    Each device's share of every buffer is put in one ``wave.put`` span
+    (``device`` = its position on the mesh's machine axis), in transfers
+    of at most ``MAX_TRANSFER_BYTES``; the span covers issuing the
+    transfers, and the caller waits for them to land.
+
     Quantized waves add the out-of-band ``meta_np`` matrix (attr + dequant
     columns); the return grows to a 3-tuple so narrow feature blocks and
     their fp32 metadata stage under the same sharding.
     """
-    spec = None if mesh is None else NamedSharding(mesh, P("machines"))
-    arrays = (blocks_np, bmask_np) + (() if meta_np is None else (meta_np,))
-    return tuple(upload(a, spec) for a in arrays)
+    arrays = tuple(np.asarray(a) for a in (blocks_np, bmask_np)
+                   + (() if meta_np is None else (meta_np,)))
+    if mesh is None:
+        with span("wave.put", tracer=tracer, wave=wave, device=0):
+            return tuple(_upload_to(a, None) for a in arrays)
+    spec = NamedSharding(mesh, P("machines"))
+    maps = [spec.addressable_devices_indices_map(a.shape) for a in arrays]
+    parts: list[list] = [[] for _ in arrays]
+    for pos, dev in enumerate(mesh.devices.flat):
+        if dev not in maps[0]:
+            continue                          # another process's device
+        with span("wave.put", tracer=tracer, wave=wave, device=pos):
+            for a, m, out in zip(arrays, maps, parts):
+                out.append(_upload_to(a[m[dev]], dev))
+    return tuple(jax.make_array_from_single_device_arrays(a.shape, spec, p)
+                 for a, p in zip(arrays, parts))
+
+
+def device_bytes(mesh: Mesh | None, arrays) -> list[int]:
+    """Bytes of ``arrays`` held on each device, by mesh position."""
+    if mesh is None:
+        return [sum(a.nbytes for a in arrays)]
+    pos = {dev: i for i, dev in enumerate(mesh.devices.flat)}
+    out = [0] * len(pos)
+    for a in arrays:
+        for s in a.addressable_shards:
+            out[pos[s.device]] += s.data.nbytes
+    return out

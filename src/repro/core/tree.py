@@ -46,7 +46,8 @@ import numpy as np
 
 from repro.core import constraints as cons_lib
 from repro.core import partition as part_lib
-from repro.core.distributed import (RoundResult, dead_wave_result, run_round,
+from repro.core.distributed import (RoundResult, dead_wave_result,
+                                    device_bytes, run_round,
                                     shard_round_inputs, stage_wave_inputs)
 from repro.core.permute import FeistelPermutation, feistel_slot_items
 from repro.core.sources import (ArraySource, GroundSetSource, as_source,
@@ -683,6 +684,7 @@ def _stream_round0(obj, source: GroundSetSource, kpart, kalg, L: int,
         return blocks, None, valid, w0, w1, False
 
     sol_rows, sol_mask = [], []
+    stage_bytes: dict[int, list[int]] = {}    # wave -> bytes per device
     carry = [best_rows, best_mask, best_val, total_calls,
              jnp.int32(0),                                 # round-depth max
              jnp.float32(-jnp.inf)]                        # [..., v_round]
@@ -701,10 +703,12 @@ def _stream_round0(obj, source: GroundSetSource, kpart, kalg, L: int,
             res = dead_wave_result(w1 - w0, cfg.k, d + a)
         else:
             with span("wave.stage", tracer=tracer, wave=i):
-                staged = stage_wave_inputs(mesh, blocks_np, valid, meta_np)
+                staged = stage_wave_inputs(mesh, blocks_np, valid, meta_np,
+                                           tracer, i)
                 # the upload returns before the copy lands; the round
                 # program waits on these arrays anyway
                 jax.block_until_ready(staged)
+            stage_bytes[i] = device_bytes(mesh, staged)
             blocks, bmask, *meta = staged      # meta: narrow waves only
             with span("wave.dispatch", tracer=tracer, wave=i):
                 res = _dispatch_blocks(obj, blocks, bmask, keys[w0:w1],
@@ -725,6 +729,9 @@ def _stream_round0(obj, source: GroundSetSource, kpart, kalg, L: int,
                        tracer=tracer)
     if supervisor is not None:
         estats.fault_stats = supervisor.stats
+    estats.mesh_devices = ndev
+    estats.stage_bytes = [stage_bytes.get(t.wave, [0] * ndev)
+                          for t in estats.traces]
     (best_rows, best_mask, best_val, total_calls, round_depth,
      v_round) = carry
 

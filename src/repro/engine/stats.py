@@ -65,6 +65,11 @@ class EngineStats:
     span_wall_s: float = 0.0    # max(t_end) − min(t_start) over the traces
     #                             (the wall the span-based overlap uses; 0.0
     #                             when the engine predates timestamped traces)
+    mesh_devices: int = 1       # devices the waves were sharded over
+    stage_bytes: list[list[int]] = dataclasses.field(default_factory=list)
+    #                             host→device bytes staged to each device
+    #                             (by mesh position) per wave, in wave order;
+    #                             a dropped wave stages nothing
 
     @property
     def width_trajectory(self) -> list[int]:

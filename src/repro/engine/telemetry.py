@@ -362,6 +362,10 @@ def feed_result_metrics(registry: MetricsRegistry, result) -> None:
         registry.counter("engine.bytes_moved", **lab).inc(es.bytes_moved)
         registry.gauge("engine.overlap_ratio", **lab).set(es.overlap_ratio)
         registry.gauge("engine.max_in_flight", **lab).set(es.max_in_flight)
+        registry.gauge("mesh.devices").set(es.mesh_devices)
+        for per_device in es.stage_bytes:
+            for dev, nbytes in enumerate(per_device):
+                registry.counter("engine.stage_bytes", device=dev).inc(nbytes)
         for t in es.traces:
             registry.histogram("engine.gather_s", **lab).observe(t.gather_s)
             registry.histogram("engine.solve_s", **lab).observe(t.solve_s)

@@ -42,6 +42,94 @@ print("OK")
 """)
 
 
+def test_streamed_tree_on_4_devices_equals_1_device_bit_for_bit():
+    # the batch deployment's path on a 4-device mesh: each wave's share
+    # lands on its device (one wave.put each), the union stays on the
+    # devices between rounds (never fetched to the host), and rounds
+    # t >= 1 spread over all four
+    _run("""
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import (ArraySource, ExemplarClustering, TreeConfig,
+                        tree_maximize, make_submod_mesh)
+from repro.core import partition as part_lib, tree
+from repro.engine import MetricsRegistry, Tracer, feed_result_metrics
+rng = np.random.default_rng(1)
+n, d, mu, k, W = 4800, 16, 100, 10, 16
+data = rng.standard_normal((n, d)).astype(np.float32)
+E = data[rng.choice(n, 64, replace=False)]
+obj = ExemplarClustering(jnp.asarray(E))
+devs = jax.devices()[:4]
+
+def cfg(tracer=None):
+    return TreeConfig(k=k, capacity=mu, seed=7, engine="pipelined",
+                      telemetry=tracer,
+                      capacity_bytes=W * mu * d * 4)
+
+unions, rounds = [], []
+repartition, dispatch = part_lib.repartition_rows, tree._dispatch_round
+def spy_repartition(rows, mask, *a, **kw):
+    unions.append(rows)
+    return repartition(rows, mask, *a, **kw)
+def spy_dispatch(*a, **kw):
+    r = dispatch(*a, **kw)
+    rounds.append(r.sol_rows)
+    return r
+part_lib.repartition_rows = spy_repartition
+tree._dispatch_round = spy_dispatch
+# every device array the run fetches to the host (the CPU backend does not
+# enforce jax.transfer_guard, so the fetch itself is watched)
+from jax._src import array as array_lib
+fetched, value = [], array_lib.ArrayImpl._value
+array_lib.ArrayImpl._value = property(
+    lambda self: (fetched.append(self.shape), value.fget(self))[1])
+tr = Tracer()
+r4 = tree_maximize(obj, ArraySource(data), cfg(tr), mesh=make_submod_mesh(devs))
+array_lib.ArrayImpl._value = value
+part_lib.repartition_rows, tree._dispatch_round = repartition, dispatch
+# only round values, counts and the final answer reach the host: the
+# union and the blocks of rounds t >= 1 stay on the devices
+assert set(fetched) == {(), (k, d), (k,)}, fetched
+r1 = tree_maximize(obj, ArraySource(data), cfg(), mesh=make_submod_mesh(devs[:1]))
+
+assert r4.machines_per_round == r1.machines_per_round == [48, 5, 1]
+np.testing.assert_array_equal(r4.sel_rows, r1.sel_rows)
+np.testing.assert_array_equal(r4.sel_mask, r1.sel_mask)
+assert r4.value == r1.value and r4.oracle_calls == r1.oracle_calls
+assert r4.round_values == r1.round_values
+
+# between rounds the union is a device array spread over the four devices
+assert len(unions) == 2
+for u in unions:
+    assert isinstance(u, jax.Array) and u.sharding.device_set == set(devs)
+    assert len({s.device for s in u.addressable_shards}) == 4
+# every round t >= 1 solves a mesh-padded machine axis split four ways
+assert [r.shape[0] for r in rounds] == [8, 4]
+for r in rounds:
+    per = {s.device: s.data.shape[0] for s in r.addressable_shards}
+    assert set(per) == set(devs) and set(per.values()) == {r.shape[0] // 4}
+
+es = r4.engine_stats
+share = W // 4 * mu * (d * 4 + 1)             # fp32 rows + bool mask
+assert es.mesh_devices == 4 and es.waves == 3
+assert es.stage_bytes == [[share] * 4] * 3, es.stage_bytes
+puts = tr.spans(cat="wave", name="put")
+assert sorted((e.args["wave"], e.args["device"]) for e in puts) == [
+    (w, i) for w in range(3) for i in range(4)]
+stages = tr.spans(cat="wave", name="stage")
+assert all(any(s.t0 <= p.t0 and p.t1 <= s.t1 for s in stages)
+           for p in puts)
+reg = MetricsRegistry()
+feed_result_metrics(reg, r4)
+snap = reg.snapshot()
+assert snap["gauges"]["mesh.devices"] == 4
+for i in range(4):
+    assert snap["counters"][f"engine.stage_bytes{{device={i}}}"] == 3 * share
+assert r1.engine_stats.mesh_devices == 1
+assert r1.engine_stats.stage_bytes == [[4 * share]] * 3
+print("OK")
+""")
+
+
 def test_gspmd_train_step_2x2_matches_single_device():
     _run("""
 import numpy as np, jax, jax.numpy as jnp

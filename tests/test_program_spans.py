@@ -77,8 +77,10 @@ def test_batch_wave_spans_on_the_profiler_clock():
     waves = res.engine_stats.waves
     assert waves == 3
     for name in ("wave.gather", "wave.read", "wave.mask", "wave.stage",
-                 "wave.dispatch", "wave.fold", "wave.block", "wave.solve"):
-        assert len(prof.spans(name)) == waves, name
+                 "wave.put", "wave.dispatch", "wave.fold", "wave.block",
+                 "wave.solve"):
+        assert len(prof.spans(name)) == waves, name                # 1 device
+    assert _inside(prof.spans("wave.put"), prof.spans("wave.stage"))
     assert _inside(prof.spans("wave.read"), prof.spans("wave.gather"))
     assert _inside(prof.spans("wave.mask"), prof.spans("wave.gather"))
     for name in ("wave.stage", "wave.dispatch", "wave.fold", "wave.block"):
@@ -240,6 +242,9 @@ READINGS = {
          ("wave.read", 11.0, 12.0)], (), 0.75),
     "wave_stage_s.batch": (
         [("wave.stage", 2.0, 2.2), ("wave.stage", 4.0, 4.6)], (), 0.4),
+    "wave_put_s.batch": (
+        [("wave.put", 2.0, 2.1), ("wave.put", 2.1, 2.3),
+         ("wave.put", 11.0, 12.0)], (), 0.15),
     "program_dispatch_s.batch": (
         [("bench.job", 0.5, 5.0), ("bench.job", 5.5, 9.0),
          ("wave.dispatch", 1.0, 1.1), ("wave.dispatch", 2.0, 2.2),

@@ -187,6 +187,11 @@ def test_feed_result_metrics_projects_stats():
     gh = snap["histograms"]["engine.gather_s{engine=pipelined}"]
     assert gh["count"] == es.waves
     assert abs(gh["sum"] - es.gather_s) < 1e-9
+    # no mesh: one device, which every wave's blocks and mask were put on
+    assert snap["gauges"]["mesh.devices"] == es.mesh_devices == 1
+    assert len(es.stage_bytes) == es.waves
+    assert (snap["counters"]["engine.stage_bytes{device=0}"]
+            == sum(b[0] for b in es.stage_bytes) > es.bytes_moved)
 
 
 # ---------------------------------------------------------------------------
